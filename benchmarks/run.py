@@ -163,6 +163,9 @@ def main() -> None:
                          "and print the trace dir (pair with --only to "
                          "profile one section)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = [args.only] if args.only else list(ALL_SECTIONS)
 

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...sharding.compat import shard_map_compat as _shard_map
 from ..events import ByteBatch, EventBatch, EventStream
 from ..nfa import NFA, MinimizeStats, QueryPartition, _query_weight, \
     compile_queries, minimize as minimize_nfa, pad_states, partition_queries
@@ -1202,10 +1201,11 @@ class FilterEngine(abc.ABC):
             vmapped = self._vmapped_parts()
             if mesh is not None:
                 ps = jax.sharding.PartitionSpec
-                return jax.jit(_shard_map(
-                    vmapped, mesh,
+                return jax.jit(jax.shard_map(
+                    vmapped, mesh=mesh,
                     in_specs=(ps("model"),) + (ps(),) * len(prep),
-                    out_specs=(ps("model"), ps("model"))))
+                    out_specs=(ps("model"), ps("model")),
+                    check_vma=False))
             return jax.jit(vmapped)
 
         return self._cached_exec(("1d", mesh), build)(stacked, *prep)
@@ -1314,10 +1314,11 @@ class FilterEngine(abc.ABC):
 
         def build():
             ps = jax.sharding.PartitionSpec
-            return jax.jit(_shard_map(
-                self._vmapped_parts(), mesh,
+            return jax.jit(jax.shard_map(
+                self._vmapped_parts(), mesh=mesh,
                 in_specs=(ps("model"),) + (ps("data"),) * len(prep),
-                out_specs=(ps("model", "data"), ps("model", "data"))))
+                out_specs=(ps("model", "data"), ps("model", "data")),
+                check_vma=False))
 
         matched, first = self._cached_exec(("2d", mesh), build)(
             stacked, *prep)
@@ -1383,10 +1384,11 @@ class FilterEngine(abc.ABC):
                 return vmapped(plan, *self._prep_arrays(*parsed))
 
             ps = jax.sharding.PartitionSpec
-            return jax.jit(_shard_map(
-                body, mesh,
+            return jax.jit(jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(ps("model"), ps("data")),
-                out_specs=(ps("model", "data"), ps("model", "data"))))
+                out_specs=(ps("model", "data"), ps("model", "data")),
+                check_vma=False))
 
         matched, first = self._cached_exec(
             ("bytes2d", mesh, n_events, max_depth), build)(

@@ -52,21 +52,39 @@ def filter_document(nfa: NFA, ev: EventStream,
 
 
 def _filter_resolved(queries, ev: EventStream) -> FilterResult:
-    """Same walk, with the name→id resolution already done."""
+    """Same walk, with the name→id resolution already done.
+
+    The last step must name the node a match lands on, so each distinct
+    step chain is only evaluated at opens of its last step's tag (a
+    wildcard last step: at every open), and only once every tag it names
+    is on the path; identical chains share one evaluation.  Same answers
+    as evaluating every query at every open.
+    """
     matched = np.zeros(len(queries), dtype=bool)
     first = np.full(len(queries), NO_MATCH, dtype=np.int32)
+    chains: dict[tuple, list[int]] = {}
+    for qi, steps in enumerate(queries):
+        if steps:          # an empty chain matches no element
+            chains.setdefault(tuple(steps), []).append(qi)
+    by_last: dict[int, list] = {}
+    for steps, qis in chains.items():
+        need = frozenset(t for _, t in steps if t != WILD_TAG)
+        by_last.setdefault(steps[-1][1], []).append(
+            (steps, need, np.asarray(qis)))
 
     path: list[int] = []
     for i in range(len(ev)):
         k = int(ev.kind[i])
         if k == OPEN:
             path.append(int(ev.tag_id[i]))
-            for qi, steps in enumerate(queries):
-                if matched[qi]:
+            on_path = set(path)
+            for steps, need, qis in (by_last.get(path[-1], [])
+                                     + by_last.get(WILD_TAG, [])):
+                if matched[qis[0]] or not need <= on_path:
                     continue
                 if _path_matches(path, steps):
-                    matched[qi] = True
-                    first[qi] = i
+                    matched[qis] = True
+                    first[qis] = i
         elif k == 1:  # CLOSE
             if path:
                 path.pop()

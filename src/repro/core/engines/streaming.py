@@ -11,8 +11,8 @@ Two executions of the same semantics:
 * **megakernel** (``kernel="pallas"``, the default device path on TPU) —
   :func:`repro.kernels.stream_filter.stream_filter_pallas`: one fused
   Pallas program gridded over (documents × state-word blocks), state
-  packed in VMEM end to end, events DMA'd through double-buffered SMEM
-  chunks.  Block tables are compiled into the plan
+  packed in VMEM end to end, events walked from SMEM chunks by the
+  scalar core.  Block tables are compiled into the plan
   (:func:`repro.kernels.blocks.state_layout`), block/chunk sizes come
   from the plan-level autotune hook
   (:meth:`repro.core.engines.base.FilterEngine.autotune_blocks`).
@@ -43,7 +43,6 @@ from ...kernels import blocks as blocks_mod
 from ...kernels import interpret_default
 from ...kernels import stream_filter as sf
 from ...kernels.parse import DEFAULT_MAX_DEPTH
-from ...sharding.compat import shard_map_compat as _shard_map
 from ..dictionary import OPEN_NBYTES
 from ..events import (CLOSE, OPEN, SEG_SENTINEL, ByteBatch, EventBatch,
                       EventStream, SegmentPack, pack_segments)
@@ -62,21 +61,16 @@ KERNEL_MODES = ("auto", "pallas", "scan")
 DEFAULT_BYTE_CHUNK = 512
 DEFAULT_SEGMENT_TARGET = 4096
 
-#: sublane tile of the fused sparse epilogue's emission window
-#: (:func:`repro.kernels.stream_filter._epilogue_window`) — autotunable
-#: and overridable via the ``ep_tile=`` engine option
-DEFAULT_EP_TILE = 8
-
-#: VMEM budget for the fused-epilogue match buffer: a ``(cap + win, 3)``
-#: int32 block pads to one 128-lane tile per row (512 B).  Past this the
-#: bounded buffer would crowd the block tables out of VMEM, so
-#: ``sparse_epilogue="auto"`` falls back to the two-launch lane
+#: VMEM budget for the fused-epilogue match buffer (three lane-dense
+#: int32 fields, :func:`repro.kernels.stream_filter.epilogue_vmem_bytes`).
+#: Past this the bounded buffer would crowd the block tables out of
+#: VMEM, so ``sparse_epilogue="auto"`` falls back to the two-launch lane
 #: compaction for that cap
 DEFAULT_EPILOGUE_VMEM = 4 * 1024 * 1024
 
 #: launch-shape knobs a measured-autotune cache entry may override
 TUNABLE_KEYS = ("blk", "chunk", "byte_chunk", "grid_order",
-                "segment_target", "ep_tile")
+                "segment_target")
 
 
 def _pack_words(bits: jax.Array) -> jax.Array:
@@ -248,33 +242,29 @@ def _run_parts_kernel_sparse(plan: base.FilterPlan, kind: jax.Array,
         mb.reshape(b, -1) != 0, fb.reshape(b, -1), lane_cls, cap)
 
 
-@functools.partial(jax.jit, static_argnames=("cap", "ep_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
 def _run_batch_kernel_fused(plan: base.FilterPlan, kind: jax.Array,
                             tag: jax.Array, doc_ids: jax.Array,
                             lane_cls: jax.Array, cap: int,
-                            ep_tile: int = DEFAULT_EP_TILE,
                             interpret: bool | None = None):
     """In-kernel sparse epilogue: the megakernel emits the bounded
     ``(doc, class, first)`` match buffer itself — the ``(B, G, QB)``
     accept bitmap never exists outside VMEM (the program's only outputs
     are the buffer and the running counter)."""
     meta = plan.meta
-    buf, cnt = sf.stream_filter_pallas_sparse(
+    return sf.stream_filter_pallas_sparse(
         sf.fuse_events(kind, tag), doc_ids,
         plan["kb_tagmask"], plan["kb_pw"], plan["kb_pb"],
         plan["kb_selfloop"], plan["kb_init"],
         plan["kb_acc_word"], plan["kb_acc_bit"], lane_cls,
         cap=cap, max_depth=meta["max_depth"], chunk=meta["chunk"],
-        interpret=interpret, grid_order=meta.get("grid_order", "bg"),
-        ep_tile=ep_tile)
-    return buf[:cap], cnt
+        interpret=interpret, grid_order=meta.get("grid_order", "bg"))
 
 
-@functools.partial(jax.jit, static_argnames=("cap", "ep_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
 def _run_parts_kernel_fused(plan: base.FilterPlan, kind: jax.Array,
                             tag: jax.Array, doc_ids: jax.Array,
                             lane_cls: jax.Array, cap: int,
-                            ep_tile: int = DEFAULT_EP_TILE,
                             interpret: bool | None = None):
     """Sharded twin of :func:`_run_batch_kernel_fused`: parts fold into
     the block grid (ONE launch) and ``lane_cls`` (P, G, QB) carries
@@ -285,45 +275,39 @@ def _run_parts_kernel_fused(plan: base.FilterPlan, kind: jax.Array,
     def fold(x):
         return x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
 
-    buf, cnt = sf.stream_filter_pallas_sparse(
+    return sf.stream_filter_pallas_sparse(
         sf.fuse_events(kind, tag), doc_ids,
         fold(plan["kb_tagmask"]), fold(plan["kb_pw"]), fold(plan["kb_pb"]),
         fold(plan["kb_selfloop"]), fold(plan["kb_init"]),
         fold(plan["kb_acc_word"]), fold(plan["kb_acc_bit"]),
         lane_cls.reshape(-1, lane_cls.shape[-1]),
         cap=cap, max_depth=meta["max_depth"], chunk=meta["chunk"],
-        interpret=interpret, grid_order=meta.get("grid_order", "bg"),
-        ep_tile=ep_tile)
-    return buf[:cap], cnt
+        interpret=interpret, grid_order=meta.get("grid_order", "bg"))
 
 
-@functools.partial(jax.jit, static_argnames=("cap", "ep_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
 def _run_bytes_fused_sparse(plan: base.FilterPlan, data: jax.Array,
                             starts: jax.Array, doc_map: jax.Array,
                             lane_cls: jax.Array, cap: int,
-                            ep_tile: int = DEFAULT_EP_TILE,
                             interpret: bool | None = None):
     """ONE launch raw bytes → bounded match list: the fused bytes
     datapath ending in the in-kernel sparse epilogue (no event tensor,
     no accept bitmap, anywhere in the program)."""
     meta = plan.meta
-    buf, cnt = sf.stream_filter_bytes_pallas_sparse(
+    return sf.stream_filter_bytes_pallas_sparse(
         data, starts, doc_map,
         plan["kb_tagmask"], plan["kb_pw"], plan["kb_pb"],
         plan["kb_selfloop"], plan["kb_init"],
         plan["kb_acc_word"], plan["kb_acc_bit"], lane_cls,
         cap=cap, max_depth=meta["max_depth"],
         chunk=meta.get("byte_chunk", DEFAULT_BYTE_CHUNK),
-        interpret=interpret, grid_order=meta.get("grid_order", "bg"),
-        ep_tile=ep_tile)
-    return buf[:cap], cnt
+        interpret=interpret, grid_order=meta.get("grid_order", "bg"))
 
 
-@functools.partial(jax.jit, static_argnames=("cap", "ep_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("cap", "interpret"))
 def _run_parts_bytes_fused_sparse(plan: base.FilterPlan, data: jax.Array,
                                   starts: jax.Array, doc_map: jax.Array,
                                   lane_cls: jax.Array, cap: int,
-                                  ep_tile: int = DEFAULT_EP_TILE,
                                   interpret: bool | None = None):
     """Stacked sharded plan through ONE bytes→match-list launch."""
     meta = plan.meta
@@ -331,7 +315,7 @@ def _run_parts_bytes_fused_sparse(plan: base.FilterPlan, data: jax.Array,
     def fold(x):
         return x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
 
-    buf, cnt = sf.stream_filter_bytes_pallas_sparse(
+    return sf.stream_filter_bytes_pallas_sparse(
         data, starts, doc_map,
         fold(plan["kb_tagmask"]), fold(plan["kb_pw"]), fold(plan["kb_pb"]),
         fold(plan["kb_selfloop"]), fold(plan["kb_init"]),
@@ -339,9 +323,7 @@ def _run_parts_bytes_fused_sparse(plan: base.FilterPlan, data: jax.Array,
         lane_cls.reshape(-1, lane_cls.shape[-1]),
         cap=cap, max_depth=meta["max_depth"],
         chunk=meta.get("byte_chunk", DEFAULT_BYTE_CHUNK),
-        interpret=interpret, grid_order=meta.get("grid_order", "bg"),
-        ep_tile=ep_tile)
-    return buf[:cap], cnt
+        interpret=interpret, grid_order=meta.get("grid_order", "bg"))
 
 
 def _device_rows(buf, cnt, cap: int, ndev: int = 1
@@ -506,9 +488,8 @@ class StreamingEngine(base.FilterEngine):
     * ``sparse_epilogue=`` — ``"auto"`` (default: in-kernel bounded
       match-list emission whenever the ``(match_cap, 3)`` buffer fits
       the epilogue VMEM budget), ``"on"`` / ``"off"`` to force it.
-    * ``ep_tile=`` — sublane tile of the fused epilogue's emission
-      window (autotunable); ``match_cap=`` — bounded match-buffer size
-      for sparse calls (also threaded via plan meta).
+    * ``match_cap=`` — bounded match-buffer size for sparse calls (also
+      threaded via plan meta).
     * ``vmem_budget=`` / ``smem_budget=`` — static autotune budgets
       (else the ``REPRO_PALLAS_*_BUDGET`` env vars, else defaults).
     * ``autotune="measured"`` — overlay the persisted measured-search
@@ -572,7 +553,6 @@ class StreamingEngine(base.FilterEngine):
         cfg.setdefault("byte_chunk", DEFAULT_BYTE_CHUNK)
         cfg.setdefault("grid_order", "bg")
         cfg.setdefault("segment_target", DEFAULT_SEGMENT_TARGET)
-        cfg.setdefault("ep_tile", DEFAULT_EP_TILE)
         if self.options.get("autotune") == "measured":
             from ...kernels import autotune as autotune_mod
 
@@ -592,7 +572,6 @@ class StreamingEngine(base.FilterEngine):
         cfg["chunk"] = max(32, int(cfg["chunk"]))
         cfg["byte_chunk"] = max(32, int(cfg["byte_chunk"]))
         cfg["segment_target"] = max(1, int(cfg["segment_target"]))
-        cfg["ep_tile"] = max(1, int(cfg["ep_tile"]))
         if cfg["grid_order"] not in sf.GRID_ORDERS:
             raise ValueError(
                 f"grid_order={cfg['grid_order']!r} is not one of "
@@ -645,8 +624,7 @@ class StreamingEngine(base.FilterEngine):
                         block_queries=mk.block_queries,
                         byte_chunk=cfg["byte_chunk"],
                         grid_order=cfg["grid_order"],
-                        segment_target=cfg["segment_target"],
-                        ep_tile=cfg["ep_tile"])
+                        segment_target=cfg["segment_target"])
             if "match_cap" in self.options:
                 meta["match_cap"] = int(self.options["match_cap"])
         return base.FilterPlan("streaming", tables, meta)
@@ -847,11 +825,7 @@ class StreamingEngine(base.FilterEngine):
 
         return self._lane_memo(sharded, build)
 
-    def _ep_tile(self, plan: base.FilterPlan) -> int:
-        return int(plan.meta.get("ep_tile", DEFAULT_EP_TILE))
-
-    def _fused_sparse_ok(self, cap: int,
-                         plan: base.FilterPlan | None = None) -> bool:
+    def _fused_sparse_ok(self, cap: int) -> bool:
         """Run the in-kernel sparse epilogue for this cap?
 
         The ``sparse_epilogue=`` engine option forces it (``"on"`` /
@@ -866,10 +840,7 @@ class StreamingEngine(base.FilterEngine):
                 f"('auto', 'on', 'off')")
         if mode != "auto":
             return mode == "on"
-        plan = self.plan_ if plan is None else plan
-        win = sf._epilogue_window(int(plan.meta["block_queries"]),
-                                  self._ep_tile(plan))
-        return (int(cap) + win) * 512 <= DEFAULT_EPILOGUE_VMEM
+        return sf.epilogue_vmem_bytes(cap) <= DEFAULT_EPILOGUE_VMEM
 
     @staticmethod
     def _mark_base_path(sp: SparseResult) -> SparseResult:
@@ -936,8 +907,7 @@ class StreamingEngine(base.FilterEngine):
             doc_ids = jnp.arange(b, dtype=jnp.int32)[:, None]
             buf, cnt = _run_batch_kernel_fused(
                 self.plan_, kind, tag, doc_ids, jnp.asarray(lane_cls),
-                cap, ep_tile=self._ep_tile(self.plan_),
-                interpret=self._kernel_interpret())
+                cap, interpret=self._kernel_interpret())
             bufs, n, over = _device_rows(buf, cnt, cap)
             path = "kernel-fused"
         else:
@@ -978,7 +948,7 @@ class StreamingEngine(base.FilterEngine):
         def dense_fallback():
             return self.filter_batch_sharded(batch, sharded, mesh=mesh)
 
-        if not self._fused_sparse_ok(cap, stacked):
+        if not self._fused_sparse_ok(cap):
             *bufs, n = _run_parts_kernel_sparse(
                 stacked, kind, tag, jnp.asarray(lane_cls.reshape(-1)),
                 cap, interpret=interpret)
@@ -987,12 +957,11 @@ class StreamingEngine(base.FilterEngine):
                 n_queries=len(live_ids), live_ids=live_ids,
                 meta={"path": "lane-compact"},
                 dense_fallback=dense_fallback)
-        ep = self._ep_tile(stacked)
         doc_ids = jnp.arange(b, dtype=jnp.int32)[:, None]
         if mesh is None:
             buf, cnt = _run_parts_kernel_fused(
                 stacked, kind, tag, doc_ids, jnp.asarray(lane_cls), cap,
-                ep_tile=ep, interpret=interpret)
+                interpret=interpret)
             bufs, n, over = _device_rows(buf, cnt, cap)
         else:
             self._check_model_axis(sharded, mesh)
@@ -1001,16 +970,17 @@ class StreamingEngine(base.FilterEngine):
                 def body(plan, kind, tag, doc_ids, lane):
                     return _run_parts_kernel_fused(
                         plan, kind, tag, doc_ids, lane, cap,
-                        ep_tile=ep, interpret=interpret)
+                        interpret=interpret)
 
                 ps = jax.sharding.PartitionSpec
-                return jax.jit(_shard_map(
-                    body, mesh,
+                return jax.jit(jax.shard_map(
+                    body, mesh=mesh,
                     in_specs=(ps("model"), ps(), ps(), ps(), ps("model")),
-                    out_specs=(ps("model"), ps("model"))))
+                    out_specs=(ps("model"), ps("model")),
+                    check_vma=False))
 
             buf, cnt = self._cached_exec(
-                ("1d-fused-sparse", mesh, cap, ep), build)(
+                ("1d-fused-sparse", mesh, cap), build)(
                 stacked, kind, tag, doc_ids, jnp.asarray(lane_cls))
             bufs, n, over = _device_rows(buf, cnt, cap,
                                          mesh.shape["model"])
@@ -1032,8 +1002,7 @@ class StreamingEngine(base.FilterEngine):
         live_ids = sharded.live_ids()
         b0 = batch.batch_size
         cap = self.match_cap(b0, len(live_ids), match_cap)
-        if not (self._kernel_on() and self._fused_sparse_ok(
-                cap, sharded.stacked())):
+        if not (self._kernel_on() and self._fused_sparse_ok(cap)):
             return self._mark_base_path(
                 super().filter_batch_sharded2d_sparse(
                     batch, sharded, mesh=mesh, match_cap=match_cap))
@@ -1047,27 +1016,27 @@ class StreamingEngine(base.FilterEngine):
         ids[b0:] = -1
         lane_cls, offsets, members = self._sharded_lane_tables(sharded)
         stacked = sharded.stacked()
-        ep = self._ep_tile(stacked)
         interpret = self._kernel_interpret()
 
         def build():
             def body(plan, kind, tag, doc_ids, lane):
                 return _run_parts_kernel_fused(
                     plan, kind, tag, doc_ids, lane, cap,
-                    ep_tile=ep, interpret=interpret)
+                    interpret=interpret)
 
             ps = jax.sharding.PartitionSpec
             # bounded buffers stack device-major on axis 0 (one (cap, 3)
             # block per device of BOTH axes), unlike the dense 2-D path
             # whose (parts, docs) axes shard independently
-            return jax.jit(_shard_map(
-                body, mesh,
+            return jax.jit(jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(ps("model"), ps("data"), ps("data"),
                           ps("data"), ps("model")),
-                out_specs=(ps(("model", "data")), ps(("model", "data")))))
+                out_specs=(ps(("model", "data")), ps(("model", "data"))),
+                check_vma=False))
 
         buf, cnt = self._cached_exec(
-            ("2d-fused-sparse", mesh, cap, ep), build)(
+            ("2d-fused-sparse", mesh, cap), build)(
             stacked, kind, tag, jnp.asarray(ids[:, None]),
             jnp.asarray(lane_cls))
         ndev = int(np.prod(list(mesh.shape.values())))
@@ -1172,10 +1141,11 @@ class StreamingEngine(base.FilterEngine):
 
             if mesh is not None:
                 ps = jax.sharding.PartitionSpec
-                return jax.jit(_shard_map(
-                    body, mesh,
+                return jax.jit(jax.shard_map(
+                    body, mesh=mesh,
                     in_specs=(ps("model"), ps(), ps()),
-                    out_specs=(ps("model"), ps("model"))))
+                    out_specs=(ps("model"), ps("model")),
+                    check_vma=False))
             return jax.jit(body)
 
         matched, first = self._cached_exec(
@@ -1224,10 +1194,11 @@ class StreamingEngine(base.FilterEngine):
                                               interpret=interpret)
 
             ps = jax.sharding.PartitionSpec
-            return jax.jit(_shard_map(
-                body, mesh,
+            return jax.jit(jax.shard_map(
+                body, mesh=mesh,
                 in_specs=(ps("model"), ps("data"), ps("data")),
-                out_specs=(ps("model", "data"), ps("model", "data"))))
+                out_specs=(ps("model", "data"), ps("model", "data")),
+                check_vma=False))
 
         matched, first = self._cached_exec(
             ("bytes2d-fused", mesh), build)(stacked, data, starts)
@@ -1267,7 +1238,6 @@ class StreamingEngine(base.FilterEngine):
         buf, cnt = _run_bytes_fused_sparse(
             self.plan_, data, starts, jnp.asarray(doc_map),
             jnp.asarray(lane_cls), cap,
-            ep_tile=self._ep_tile(self.plan_),
             interpret=self._kernel_interpret())
         bufs, n, over = _device_rows(buf, cnt, cap)
         return self._expand_class_hits(
@@ -1287,41 +1257,39 @@ class StreamingEngine(base.FilterEngine):
         live_ids = sharded.live_ids()
         b = bb.batch_size
         cap = self.match_cap(b, len(live_ids), match_cap)
-        stacked = sharded.stacked()
-        if not (self._fused_bytes_on()
-                and self._fused_sparse_ok(cap, stacked)):
+        if not (self._fused_bytes_on() and self._fused_sparse_ok(cap)):
             return super().filter_bytes_sharded_sparse(
                 bb, sharded, bucket=bucket, mesh=mesh,
                 match_cap=match_cap)
         self._check_model_axis(sharded, mesh)
+        stacked = sharded.stacked()
         data, starts, spk = self._bytes_prep(bb)
         doc_map = (spk.doc_ids if spk is not None
                    else np.arange(b, dtype=np.int32)[:, None])
         lane_cls, offsets, members = self._sharded_lane_tables(sharded)
-        ep = self._ep_tile(stacked)
         interpret = self._kernel_interpret()
         if mesh is None:
             buf, cnt = _run_parts_bytes_fused_sparse(
                 stacked, data, starts, jnp.asarray(doc_map),
-                jnp.asarray(lane_cls), cap, ep_tile=ep,
-                interpret=interpret)
+                jnp.asarray(lane_cls), cap, interpret=interpret)
             bufs, n, over = _device_rows(buf, cnt, cap)
         else:
             def build():
                 def body(plan, data, starts, doc_map, lane):
                     return _run_parts_bytes_fused_sparse(
                         plan, data, starts, doc_map, lane, cap,
-                        ep_tile=ep, interpret=interpret)
+                        interpret=interpret)
 
                 ps = jax.sharding.PartitionSpec
-                return jax.jit(_shard_map(
-                    body, mesh,
+                return jax.jit(jax.shard_map(
+                    body, mesh=mesh,
                     in_specs=(ps("model"), ps(), ps(), ps(),
                               ps("model")),
-                    out_specs=(ps("model"), ps("model"))))
+                    out_specs=(ps("model"), ps("model")),
+                    check_vma=False))
 
             buf, cnt = self._cached_exec(
-                ("bytes1d-fused-sparse", mesh, cap, ep), build)(
+                ("bytes1d-fused-sparse", mesh, cap), build)(
                 stacked, data, starts, jnp.asarray(doc_map),
                 jnp.asarray(lane_cls))
             bufs, n, over = _device_rows(buf, cnt, cap,

@@ -204,7 +204,9 @@ class FilterStage:
         self.stats = {"batches": 0, "docs": 0, "bytes": 0,
                       "seconds": 0.0, "pair_matches": 0, "pairs": 0,
                       "put_seconds": 0.0, "overlapped_batches": 0,
-                      "verdict_bytes": 0, "rebalances": 0}
+                      "verdict_bytes": 0, "rebalances": 0,
+                      # sparse batches per engine route (``meta["path"]``)
+                      "verdict_paths": {}}
         # plan epoch: bumped on every committed plan change; the mutex
         # covers only snapshot/commit (reference assignments), never a
         # compile — prepare_* does the expensive work outside it
@@ -441,6 +443,9 @@ class FilterStage:
             self.stats["pair_matches"] += res.n_matches
             self.stats["pairs"] += res.batch_size * res.n_live
             self.stats["verdict_bytes"] += res.verdict_bytes
+            paths = self.stats["verdict_paths"]
+            path = res.meta.get("path")
+            paths[path] = paths.get(path, 0) + 1
         else:
             self.stats["pair_matches"] += int(res.matched.sum())
             self.stats["pairs"] += res.matched.size
@@ -463,6 +468,7 @@ class FilterStage:
                                              mesh=self.mesh)
             if self.sparse:
                 res = res.sparsify(sharded.live_ids())
+                res.meta["path"] = "dense-2d"
         elif sharded is not None:
             res = (eng.filter_bytes_sharded_sparse if self.sparse
                    else eng.filter_bytes_sharded)(

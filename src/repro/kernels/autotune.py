@@ -25,11 +25,9 @@ CLI (exercised by CI with a 2-trial cap under interpret)::
 
 Writes/updates the cache and prints the per-candidate timings as JSON.
 
-Migration note: :data:`KEY_VERSION` 2 added the fused-sparse-epilogue
-``ep_tile`` dimension; v1 keys (no ``v…:`` prefix) are simply never read
-again, so stale ``(blk, byte_chunk, grid_order, segment_target)``
-entries can't mis-configure the fused kernel — re-run the search to
-repopulate.
+Migration note: :data:`KEY_VERSION` 3 dropped the ``ep_tile``
+dimension (the fused epilogue no longer has an emission window); older
+keys are simply never read again — re-run the search to repopulate.
 """
 from __future__ import annotations
 
@@ -44,9 +42,10 @@ from typing import Any, Mapping, Sequence
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 DEFAULT_CACHE = "~/.cache/repro/autotune.json"
 
-#: bumped whenever the tunable-config schema changes (v2: ``ep_tile``);
-#: part of every :func:`plan_key`, so old-schema entries miss cleanly
-KEY_VERSION = 2
+#: bumped whenever the tunable-config schema changes (v3: no
+#: ``ep_tile``); part of every :func:`plan_key`, so old-schema entries
+#: miss cleanly
+KEY_VERSION = 3
 
 #: candidate grids for the measured search (kept small: the search is
 #: measured, so every candidate costs a compile + ``trials`` timed runs)
@@ -54,7 +53,6 @@ DEFAULT_BLKS = (32, 64, 128)
 DEFAULT_BYTE_CHUNKS = (128, 256, 512)
 DEFAULT_GRID_ORDERS = ("bg", "gb")
 DEFAULT_SEGMENT_TARGETS = (2048, 4096)
-DEFAULT_EP_TILES = (8, 32)
 
 
 # ------------------------------------------------------------------- cache
@@ -115,9 +113,8 @@ def cached_config(key: str, path: str | None = None) -> dict | None:
 # ------------------------------------------------------------------ search
 def _time_engine(eng, bb, trials: int) -> float:
     """Best-of-``trials`` wall seconds for one packed filter_bytes call
-    plus one packed sparse call (the fused-epilogue path — the
-    ``ep_tile`` dimension only matters there); the first, untimed calls
-    pay compilation."""
+    plus one packed sparse call (the fused-epilogue path); the first,
+    untimed calls pay compilation."""
     eng.filter_bytes(bb, pack=True)
     eng.filter_bytes_sparse(bb, pack=True)
     best = float("inf")
@@ -134,7 +131,6 @@ def search(nfa, dictionary, bb, *, max_depth: int | None = None,
            byte_chunks: Sequence[int] = DEFAULT_BYTE_CHUNKS,
            grid_orders: Sequence[str] = DEFAULT_GRID_ORDERS,
            segment_targets: Sequence[int] = DEFAULT_SEGMENT_TARGETS,
-           ep_tiles: Sequence[int] = DEFAULT_EP_TILES,
            trials: int = 3, interpret: bool | None = None,
            cache: bool = True, cache_file: str | None = None
            ) -> tuple[dict, list[dict]]:
@@ -155,11 +151,10 @@ def search(nfa, dictionary, bb, *, max_depth: int | None = None,
         max_depth = DEFAULT_MAX_DEPTH
     rows: list[dict] = []
     best: dict | None = None
-    for blk, bc, go, st, ep in itertools.product(
-            blks, byte_chunks, grid_orders, segment_targets, ep_tiles):
+    for blk, bc, go, st in itertools.product(
+            blks, byte_chunks, grid_orders, segment_targets):
         cfg = {"blk": int(blk), "byte_chunk": int(bc),
-               "grid_order": str(go), "segment_target": int(st),
-               "ep_tile": int(ep)}
+               "grid_order": str(go), "segment_target": int(st)}
         try:
             eng = engines.create(
                 "streaming", nfa, dictionary=dictionary,
@@ -187,7 +182,7 @@ def search(nfa, dictionary, bb, *, max_depth: int | None = None,
         entries[key] = {
             "config": {k: best[k] for k in
                        ("blk", "byte_chunk", "grid_order",
-                        "segment_target", "ep_tile")},
+                        "segment_target")},
             "seconds": best["seconds"],
             "trials": int(trials),
             "timestamp": time.time(),
@@ -224,7 +219,6 @@ def main(argv: Sequence[str] | None = None) -> int:
                     default=DEFAULT_GRID_ORDERS)
     ap.add_argument("--segment-targets", type=_int_list,
                     default=DEFAULT_SEGMENT_TARGETS)
-    ap.add_argument("--ep-tiles", type=_int_list, default=DEFAULT_EP_TILES)
     ap.add_argument("--cache", default=None,
                     help=f"cache file (default ${CACHE_ENV} or "
                          f"{DEFAULT_CACHE})")
@@ -247,7 +241,7 @@ def main(argv: Sequence[str] | None = None) -> int:
     best, rows = search(
         nfa, d, bb, blks=args.blks, byte_chunks=args.byte_chunks,
         grid_orders=args.grid_orders, segment_targets=args.segment_targets,
-        ep_tiles=args.ep_tiles, trials=args.trials, cache_file=args.cache)
+        trials=args.trials, cache_file=args.cache)
     print(json.dumps({"best": best, "rows": rows,
                       "cache": cache_path(args.cache)}, indent=2))
     return 0

@@ -9,6 +9,15 @@ from __future__ import annotations
 import jax
 
 
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes.  The code places arrays with
+    ``shard_map`` and sharding constraints and carries no shardings in
+    its types; ``jax.make_mesh`` defaults to Explicit axes, under which
+    reshapes and gathers of sharded values are refused."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod; multi-pod adds a leading 2-pod axis.
 
@@ -18,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -29,7 +38,7 @@ def make_host_mesh(model: int = 1):
         raise ValueError(
             f"cannot build host mesh: {n} devices not divisible by "
             f"model={model}")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _make_mesh((n // model, model), ("data", "model"))
 
 
 def make_filter_mesh(n_parts: int | None = None, *, data_shards: int = 1):
@@ -68,4 +77,4 @@ def make_filter_mesh(n_parts: int | None = None, *, data_shards: int = 1):
     if n_parts is not None:
         while n_parts % model != 0:
             model -= 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _make_mesh((data, model), ("data", "model"))

@@ -54,6 +54,7 @@ from repro.core.dictionary import TagDictionary
 from repro.core.events import encode_bytes
 from repro.data.filter_stage import TEXT_FILL, FilterStage
 from repro.data.generator import DTD, gen_corpus, gen_profiles
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import ServeEngine
 from repro.serve.loop import OVERLOAD_POLICIES, ServeLoop, make_arrivals, run_trace
@@ -205,6 +206,7 @@ def main() -> None:
                          "restarts with the same subscription set skip "
                          "plan recompilation (crash-recovery cold start)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced).with_(vocab=256)
     params = T.init_model(cfg, jax.random.PRNGKey(0))

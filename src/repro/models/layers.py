@@ -15,7 +15,6 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..sharding import constrain
-from ..sharding.compat import shard_map_compat as _shard_map
 from .config import ModelConfig
 
 Params = dict[str, Any]
@@ -455,11 +454,12 @@ def _moe_ep_shardmap(cfg: ModelConfig, p: Params, x2: jax.Array,
         return jax.lax.psum(y, "model")
 
     P_ = jax.sharding.PartitionSpec
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_(dp_axes or None, None), P_(None, None),
                   P_("model", None, None), P_("model", None, None)),
         out_specs=P_(dp_axes or None, None),
+        check_vma=False,
     )(x2, p["router"], p["wi"], p["wo"])
 
 
@@ -565,11 +565,12 @@ def _moe_ep_stationary(cfg: ModelConfig, p: Params, x2: jax.Array,
         return jax.lax.psum(y, ("model", "data"))
 
     P_ = jax.sharding.PartitionSpec
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P_(None, "data"), P_("data", None),
                   P_("model", "data", None), P_("model", "data", None)),
         out_specs=P_(None, None),
+        check_vma=False,
     )(x2, p["router"], p["wi"], p["wo"])
 
 

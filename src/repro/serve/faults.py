@@ -330,6 +330,9 @@ def main(argv: Any = None) -> int:
     ap.add_argument("--out", default=None, metavar="PATH",
                     help="write the chaos report JSON here (CI artifact)")
     args = ap.parse_args(argv)
+    from ..launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     stage_opts = ({"query_shards": args.query_shards}
                   if args.query_shards > 1 else {})

@@ -56,7 +56,10 @@ Fault tolerance (the loop keeps serving through all of these):
   the bisection.  Poison requests are *quarantined* into a bounded
   dead-letter buffer (:attr:`ServeLoop.dead_letter`) with their typed
   error; the co-batched healthy requests are re-filtered and complete
-  with verdicts bit-identical to a fault-free run.
+  with verdicts bit-identical to a fault-free run.  An error that every
+  subset reproduces is the device path's, not a document's: it fails
+  the loop (:meth:`ServeLoop.close` re-raises it) instead of
+  quarantining every request.
 * **Shadow-plan hot swap** — :meth:`ServeLoop.subscribe` /
   :meth:`unsubscribe` / :meth:`rebalance` build the replacement plan on
   a background builder thread (``FilterStage.prepare_*``) and the
@@ -236,6 +239,9 @@ class ServeLoop:
         self._t_first: float | None = None
         self._t_last: float | None = None
         self._batches_since_rebalance = 0
+        #: a payload the device path served, re-run to tell a poison
+        #: document from a broken path (see :meth:`_recover`)
+        self._last_good: bytes | None = None
         self._auto_ticket: ReconfigTicket | None = None
         self._reconfig_cv = threading.Condition()
         self._reconfig_q: deque = deque()
@@ -412,8 +418,10 @@ class ServeLoop:
                 try:
                     res, nbytes, dt, ep = future.result()
                 except BaseException as e:
-                    if self.recover:
-                        self._recover(reqs, e, retry=True)
+                    # once the device path itself has failed, later
+                    # batches fail fast instead of bisecting again
+                    if self.recover and self._error is None:
+                        self._recover(reqs, e)
                     else:
                         self._fail_requests(reqs, e)
                 else:
@@ -444,6 +452,7 @@ class ServeLoop:
             self._latencies.append(t_done - r.t_submit)
             r.done.set()
         self.counters["completed"] += len(reqs)
+        self._last_good = reqs[-1].payload
         self._t_last = t_done
         self._batch_fills.append(len(reqs) / self.max_batch)
         if self.deliver is not None:
@@ -456,50 +465,75 @@ class ServeLoop:
                 self.counters["delivery_errors"] += 1
 
     # ------------------------------------------------- failure containment
-    def _recover(self, reqs: list[ServeRequest], err: BaseException,
-                 retry: bool) -> None:
-        """Contain a failed batch: isolate poison, save the rest.
+    def _recover(self, reqs: list[ServeRequest], err: BaseException) -> None:
+        """Contain a failed batch: isolate poison, save the rest — or
+        fail the loop when the fault is not the documents' at all.
 
-        A typed :class:`DocumentError` carrying ``doc_indices`` names
-        the poison outright — quarantine those, re-filter the rest.
-        Anything else gets one whole-batch retry (transient faults:
+        Typed :class:`DocumentError`\\ s are quarantined as they come.
+        Any other error gets one whole-batch retry (transient faults:
         worker hiccup, OOM race), then bisection: halves re-filter
-        independently, singletons that still fail are quarantined as
-        :class:`KernelFault`.  Healthy co-batched documents therefore
-        always complete, with verdicts identical to a fault-free run.
+        independently, and singletons that still fail are *suspects*.
+        Suspects are quarantined as :class:`KernelFault` only when the
+        fault is a document's — some subset of the batch was served, or
+        a payload served earlier still is.  Otherwise every subset fails
+        alike (a lowering, compile or device error): the requests fail
+        with the raw error, which becomes the loop error that
+        :meth:`close` re-raises, instead of a dead letter per request.
         """
-        if isinstance(err, DocumentError) and err.doc_indices:
-            # pad rows repeat the last payload, so a pad-row index maps
-            # back onto the last real request
-            bad_idx = sorted({min(int(i), len(reqs) - 1)
-                              for i in err.doc_indices})
-            bad = set(bad_idx)
-            self._quarantine([reqs[i] for i in bad_idx], err)
-            rest = [r for i, r in enumerate(reqs) if i not in bad]
-            if rest:
-                self._try_subset(rest)
-            return
-        if retry:
+        suspects: list[tuple[ServeRequest, Exception]] = []
+        if _names_documents(err):
+            served = self._isolate(reqs, err, suspects)
+        else:
             self.counters["retries"] += 1
-            self._try_subset(reqs)
+            served = self._try_subset(reqs, suspects)
+        if not suspects:
             return
-        if len(reqs) == 1:
-            self._quarantine(reqs, err)
-            return
-        mid = len(reqs) // 2
-        self._try_subset(reqs[:mid])
-        self._try_subset(reqs[mid:])
+        settle = (self._quarantine if served or self._control_ok()
+                  else self._fail_requests)
+        for r, e in suspects:
+            settle([r], e)
 
-    def _try_subset(self, reqs: list[ServeRequest]) -> None:
-        """Synchronously re-filter a subset on the completer thread;
-        recurse into :meth:`_recover` (no further whole-batch retry) if
-        it fails again."""
+    def _isolate(self, reqs: list[ServeRequest], err: DocumentError,
+                 suspects: list) -> bool:
+        """Quarantine the documents a typed error names and re-filter
+        the rest; returns whether any of the rest was served."""
+        # pad rows repeat the last payload, so a pad-row index maps back
+        # onto the last real request
+        bad = {min(int(i), len(reqs) - 1) for i in err.doc_indices}
+        self._quarantine([reqs[i] for i in sorted(bad)], err)
+        rest = [r for i, r in enumerate(reqs) if i not in bad]
+        return bool(rest) and self._try_subset(rest, suspects)
+
+    def _try_subset(self, reqs: list[ServeRequest], suspects: list) -> bool:
+        """Synchronously re-filter a subset on the completer thread,
+        bisecting on failure; untyped singleton failures go to
+        ``suspects`` as ``(request, error)``.  Returns whether any part
+        of the subset was served."""
         try:
             res, nbytes, dt, ep = self._run_batch([r.payload for r in reqs])
-        except BaseException as e:
-            self._recover(reqs, e, retry=False)
-            return
+        except Exception as e:
+            if _names_documents(e):
+                return self._isolate(reqs, e, suspects)
+            if len(reqs) == 1:
+                suspects.append((reqs[0], e))
+                return False
+            mid = len(reqs) // 2
+            left = self._try_subset(reqs[:mid], suspects)
+            return self._try_subset(reqs[mid:], suspects) or left
         self._resolve(reqs, res, nbytes, dt, ep)
+        return True
+
+    def _control_ok(self) -> bool:
+        """Does a payload that was served before still filter?  Tells a
+        poison document (yes) from a broken device path (no, or nothing
+        was ever served)."""
+        if self._last_good is None:
+            return False
+        try:
+            self._run_batch([self._last_good])
+        except Exception:
+            return False
+        return True
 
     def _quarantine(self, reqs: list[ServeRequest],
                     err: BaseException) -> None:
@@ -748,6 +782,11 @@ class ServeLoop:
         edges = np.geomspace(lo, hi, n_bins + 1)
         counts, _ = np.histogram(lat, bins=edges)
         return {"edges_ms": edges.tolist(), "counts": counts.tolist()}
+
+
+def _names_documents(err: BaseException) -> bool:
+    """A typed document error that names the batch rows at fault."""
+    return isinstance(err, DocumentError) and bool(err.doc_indices)
 
 
 def _pct(xs: np.ndarray, q: float) -> float:

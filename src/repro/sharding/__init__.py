@@ -1,3 +1,2 @@
 """Distribution: logical-axis sharding rules, mesh helpers, context."""
-from .compat import shard_map_compat  # noqa: F401
 from .ctx import constrain, axis_size, mesh_context  # noqa: F401

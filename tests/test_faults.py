@@ -252,6 +252,67 @@ class TestQuarantine:
                                 + s["quarantined"])
 
 
+class TestSystemicFault:
+    """An error that every subset reproduces is the device path's (a
+    lowering, compile or runtime fault), not a document's: it fails the
+    loop instead of becoming a dead letter per request."""
+
+    def test_every_subset_failing_fails_the_loop(self):
+        profiles, d, _, raw = _workload(n_docs=8)
+        stage = _stage(profiles, d)
+        _Poisoner(stage, set(raw))     # every payload fails: a broken path
+        loop = ServeLoop(stage, max_batch=BATCH, deadline_ms=60_000,
+                         queue_cap=64)
+        tickets = [loop.submit(p) for p in raw]
+        with pytest.raises(RuntimeError, match="poisoned batch"):
+            loop.close()
+        for t in tickets:
+            assert t.failed and not isinstance(t.error, KernelFault)
+        s = loop.slo_summary()
+        assert s["quarantined"] == 0 and len(loop.dead_letter) == 0
+        assert s["failed"] == len(raw)
+        assert s["arrived"] == (s["completed"] + s["shed"] + s["failed"]
+                                + s["quarantined"])
+
+    def test_lone_poison_after_a_served_batch_is_quarantined(self):
+        """A poison document alone in its batch: a payload served
+        earlier still filters, so the fault is the document's."""
+        profiles, d, _, raw = _workload(n_docs=BATCH)
+        bad = raw[0] + d.open_bytes(1) + d.close_bytes(1)
+        stage = _stage(profiles, d)
+        _Poisoner(stage, {bad})
+        loop = ServeLoop(stage, max_batch=BATCH, deadline_ms=60_000,
+                         queue_cap=64)
+        with loop:
+            healthy = [loop.submit(p) for p in raw]
+            for t in healthy:
+                assert t.done.wait(timeout=120)
+            lone = loop.submit(bad)        # flushed alone at close()
+        assert all(not t.failed for t in healthy)
+        assert lone.failed and isinstance(lone.error, KernelFault)
+        s = loop.slo_summary()
+        assert s["quarantined"] == 1 and s["failed"] == 0
+        assert [r["payload"] for r in loop.dead_letter] == [bad]
+
+    def test_serve_cli_exits_nonzero(self, monkeypatch):
+        """``python -m repro.launch.serve --arrival …`` raises out of
+        ``main`` (a non-zero exit) when the device path is broken."""
+        import sys
+
+        import repro.launch.serve as serve
+
+        def broken(self, bufs, record=True, epoch=None):
+            raise RuntimeError("lowering failed")
+
+        monkeypatch.setattr(FilterStage, "_filter_bytebatch", broken)
+        monkeypatch.setattr(sys, "argv", [
+            "serve", "--requests", "4", "--replicas", "1", "--batch", "2",
+            "--prompt-len", "4", "--gen-len", "2", "--arrival", "replay",
+            "--rate", "2000", "--filter-engine", ENGINE])
+        with pytest.raises(RuntimeError, match="lowering failed"):
+            serve.main()
+
+
 def _verdict_sets(routes: dict) -> dict:
     out: dict[int, list] = {}
     for (_, shard), matched in sorted(routes.items()):

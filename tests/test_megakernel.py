@@ -4,11 +4,11 @@ The Pallas megakernel (``StreamingEngine(kernel="pallas")``) must be
 *bit-identical* to the ``lax.scan`` path (``kernel="scan"``) on every
 plan and every batch — ragged/padded batches, churned (add/remove-query)
 sharded plans, depth-overflow documents, fused byte ingestion and the
-2-D mesh program.  Tests are parametrized over interpret mode (runs
-everywhere) and compiled mode (runs only on a real TPU backend).
+2-D mesh program.  Tests run the kernels in interpret mode; the compiled
+mode is covered by ``tests/test_chip_compile.py`` (Mosaic compiles the
+same kernels for a described TPU) and by ``chip_smoke.py`` on the chip.
 """
 
-import jax
 import numpy as np
 import pytest
 
@@ -20,14 +20,8 @@ from repro.core.events import (CLOSE, OPEN, ByteBatch, EventBatch,
 from repro.core.nfa import compile_queries
 from repro.data.generator import DTD, gen_corpus, gen_profiles
 
-#: interpret=True runs on any backend; interpret=False (the compiled
-#: megakernel) only on a real TPU
-MODES = [
-    pytest.param(True, id="interpret"),
-    pytest.param(False, id="compiled", marks=pytest.mark.skipif(
-        jax.default_backend() != "tpu",
-        reason="compiled Pallas needs a TPU backend")),
-]
+#: the Pallas interpreter runs on any backend
+MODES = [pytest.param(True, id="interpret")]
 
 
 def workload(n_queries=32, seed=0, n_tags=14, p_wild=0.1, p_desc=0.3,

@@ -11,8 +11,8 @@ PR-level contract, four legs:
   all-docs-match-everything are each bit-exact against the scan oracle
   via ``densify()`` on the plain, sharded, bytes and churned-gid paths.
 * **No bitmap in HBM** — a jaxpr inspection asserts the fused program's
-  ``pallas_call`` outputs are ONLY the bounded ``(cap + win, 3)`` match
-  buffer and the ``(1, 1)`` counter: the ``(B, G, QB)`` accept bitmap
+  ``pallas_call`` outputs are ONLY the bounded field-major
+  ``(3, rows, 128)`` match buffer and the ``(1, 1)`` counter: the ``(B, G, QB)`` accept bitmap
   never materializes outside VMEM.
 * **Kernel vs oracle** — the raw kernel's buffer equals
   :func:`repro.kernels.ref.sparse_epilogue` row for row (emission order
@@ -131,6 +131,21 @@ class TestPathTaxonomy:
             assert sp.meta["launch"] == "bytes"
             _assert_dense_parity(sp, eng.filter_batch(batch))
 
+    def test_stage_counts_batches_per_route(self):
+        """A sparse FilterStage tallies each batch's route, so a run
+        can show that every batch stayed on the fused kernel."""
+        from repro.core.events import encode_bytes
+        from repro.data.filter_stage import TEXT_FILL, FilterStage
+
+        eng, d, docs, dtd = _workload(n_docs=6)
+        profiles = gen_profiles(dtd, n=12, length=3, p_desc=0.4,
+                                p_wild=0.15, seed=0)
+        stage = FilterStage(profiles, d, engine="streaming", sparse=True,
+                            batch_size=3, engine_options=KERNEL_OPTS)
+        raw = [encode_bytes(x, text_fill=TEXT_FILL) for x in docs]
+        list(stage.route_bytes(raw))
+        assert stage.stats["verdict_paths"] == {"kernel-fused": 2}
+
     def test_sharded2d_sparse_fused(self):
         eng, d, docs, _ = _workload(n_docs=6)
         batch = EventBatch.from_streams(docs, bucket=64)
@@ -243,12 +258,12 @@ class TestNoBitmapInHBM:
 
         calls = _pallas_eqns(jax.make_jaxpr(fused)().jaxpr)
         assert len(calls) == 1, "fusion means ONE pallas_call"
-        win = sf._epilogue_window(meta["block_queries"], 8)
+        rows = sf._buffer_rows(cap)
         shapes = sorted(tuple(v.aval.shape) for v in calls[0].outvars)
-        assert shapes == sorted([(cap + win, 3), (1, 1)]), (
+        assert shapes == sorted([(3, rows, sf.LANES), (1, 1)]), (
             "the fused program may emit ONLY the bounded match buffer "
             f"and its counter, got {shapes}")
-        assert all(len(s) != 3 for s in shapes), \
+        assert all(np.prod(s) <= 3 * rows * sf.LANES for s in shapes), \
             "no (B, G, QB) accept bitmap may reach HBM"
 
     def test_dense_program_does_materialize_the_bitmap(self):
@@ -270,7 +285,8 @@ class TestNoBitmapInHBM:
                 interpret=True)
 
         calls = _pallas_eqns(jax.make_jaxpr(dense)().jaxpr)
-        assert any(len(v.aval.shape) == 3 for c in calls
+        bitmap = (batch.batch_size, meta["n_blocks"])
+        assert any(tuple(v.aval.shape[:2]) == bitmap for c in calls
                    for v in c.outvars)
 
 
