@@ -1,0 +1,168 @@
+"""The harness on the CPU: result line, window arithmetic, and that the
+comparison refuses the control and the planted faults."""
+import json
+import math
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from bench import harness
+from bench.tests.tiny import make_root
+
+ROOT = Path(__file__).resolve().parents[2]
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return make_root(tmp_path_factory.mktemp("checkout"))
+
+
+def run_cell(root, capsys, cell, seconds="1.5", seed="4294967311"):
+    import jax
+
+    rc = harness.main(["--workload", cell, "--seed", seed, "--seconds",
+                       seconds, "--trace", "0"], root=root,
+                      check=lambda chips: jax.devices())
+    out = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0
+    return json.loads(out[-1])
+
+
+@pytest.mark.parametrize("cell,metrics", [
+    ("tiny.backlog", {"mb_per_s", "setup_s"}),
+    ("tiny.poisson", {"p50_ms", "p95_ms", "setup_s"})])
+def test_result_line_has_the_contracts_keys(root, capsys, cell, metrics):
+    res = run_cell(root, capsys, cell)
+    assert CONTRACT_KEYS <= set(res) <= CONTRACT_KEYS | {"checks"}
+    assert list(res)[-1] == "checks"
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["attempted"] > 0
+    assert set(res["metrics"]) == metrics
+    assert all(m["value"] > 0 for m in res["metrics"].values())
+    assert set(res["device"]) == {"platform", "kind", "count",
+                                  "memory_peak_bytes"}
+    assert res["checks"] == {"wrong_lists": {"value": 0, "limit": 0},
+                             "missing": {"value": 0, "limit": 0}}
+
+
+def _drop_half(orig):
+    def fan_out(self, *a, **k):
+        return [rd for i, rd in enumerate(orig(self, *a, **k)) if i % 2]
+    return fan_out
+
+
+def _alter_first(orig):
+    def fan_out(self, *a, **k):
+        out = orig(self, *a, **k)
+        m = out[0].matched_profiles
+        out[0].matched_profiles = (m[1:] if len(m)
+                                   else np.asarray([0], np.int32))
+        return out
+    return fan_out
+
+
+@pytest.mark.parametrize("cell", ["tiny.backlog", "tiny.poisson"])
+@pytest.mark.parametrize("fault,check", [(_drop_half, "missing"),
+                                         (_alter_first, "wrong_lists")])
+def test_planted_faults_are_not_correct(root, capsys, monkeypatch, cell,
+                                        fault, check):
+    from repro.data.filter_stage import FilterStage
+
+    monkeypatch.setattr(FilterStage, "_fan_out",
+                        fault(FilterStage._fan_out))
+    res = run_cell(root, capsys, cell)
+    assert res["correct"] is False
+    assert res["checks"][check]["value"] > res["checks"][check]["limit"]
+
+
+@pytest.mark.parametrize("cell", ["tiny.backlog", "tiny.poisson"])
+def test_control_is_not_correct(root, capsys, cell):
+    from bench import control
+
+    restore = control.install(root / "bench" / "configs" / "linear_xpath.py")
+    try:
+        res = run_cell(root, capsys, cell)
+    finally:
+        restore()
+    assert res["correct"] is False
+    assert res["checks"]["wrong_lists"]["value"] > 0
+
+
+class _Ticket:
+    def __init__(self, seq, payload=b"x" * 10, shed=False):
+        self.seq, self.payload, self.shed, self.error = seq, payload, shed, None
+
+
+class _Doc:
+    def __init__(self, seq):
+        self.doc_index, self.matched_profiles = seq, np.zeros(0, np.int32)
+
+
+def _bare_run(kind, seconds=1.0):
+    spec = harness.CellSpec("c", 1, {}, {"arrivals": {"kind": kind}},
+                            [], [])
+    run = harness.Run(spec, 1, seconds, 0.0)
+    run.edge0, run.edge1, run.t_setup = {}, {}, 1.0
+    run.dep = type("D", (), {"profiles": []})()
+    return run
+
+
+def test_mb_per_s_counts_only_deliveries_inside_the_window():
+    run = _bare_run("backlog")
+    run.tickets = [_Ticket(i) for i in range(8)]
+    # batches delivered at t = 1 (window opens), 2, 3, 4 (window closes),
+    # 5 (after): only the batches at 2, 3 and 4 count
+    run.deliveries = [harness.Delivery(t, [_Doc(2 * k), _Doc(2 * k + 1)])
+                      for k, t in enumerate([1.0, 2.0, 3.0, 4.0])]
+    run.t_window, run.due = (1.0, 4.0), None
+    ctx, rec = run.collect()
+    assert ctx.window_docs == 6 and ctx.window_bytes == 60
+    assert ctx.window_s == 3.0
+    mod = harness.load_module(ROOT / "bench" / "metrics" / "mb_per_s.py")
+    assert mod.read(ctx) == pytest.approx(60 / 1e6 / 3.0)
+
+
+def test_latency_counts_shed_and_undelivered_at_the_cutoff():
+    run = _bare_run("poisson")
+    run.tickets = [_Ticket(0), _Ticket(1), _Ticket(-1, shed=True),
+                   _Ticket(2)]
+    run.deliveries = [harness.Delivery(10.1, [_Doc(0)]),
+                      harness.Delivery(10.3, [_Doc(1)])]
+    run.due = np.asarray([10.0, 10.1, 10.2, 10.3])
+    run.t_window, run.cutoff = (10.0, 11.0), 71.0
+    ctx, _ = run.collect()
+    assert ctx.latencies_ms == pytest.approx([100, 200, 60800, 60700])
+    p50 = harness.load_module(ROOT / "bench" / "metrics" / "p50_ms.py")
+    assert p50.read(ctx) == pytest.approx(200)
+    assert harness.nearest_rank(ctx.latencies_ms, 95) == pytest.approx(60800)
+
+
+def test_nearest_rank():
+    xs = np.arange(1, 101, dtype=float)
+    assert harness.nearest_rank(xs, 50) == 50
+    assert harness.nearest_rank(xs, 95) == 95
+    assert harness.nearest_rank(np.append(xs[:99], math.inf), 100) \
+        == math.inf
+
+
+def test_unknown_device_kind_raises():
+    harness.peaks_for("TPU v5 lite", ROOT)
+    with pytest.raises(KeyError):
+        harness.peaks_for("TPU v99", ROOT)
+
+
+@pytest.mark.parametrize("env", [{"JAX_PLATFORMS": "cpu"},
+                                 {"REPRO_PALLAS_INTERPRET": "1"}])
+def test_off_the_chip_exits_nonzero_without_a_result(env):
+    proc = subprocess.run(
+        [sys.executable, str(ROOT / "bench" / "run.py"), "--workload",
+         "xmark-1k.backlog", "--seed", "1", "--seconds", "1"],
+        env={**os.environ, "JAX_PLATFORMS": "cpu", **env},
+        capture_output=True, text=True, timeout=120, cwd=ROOT)
+    assert proc.returncode != 0
+    assert not any(line.startswith("{") for line in proc.stdout.splitlines())
