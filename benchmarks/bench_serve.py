@@ -6,7 +6,7 @@ docs/s — so this section measures the continuous serve loop
 (:mod:`repro.serve.loop`) as a service: seeded Poisson and bursty
 (ON/OFF) arrival traces are driven open-loop through admission control,
 adaptive batching and K-deep dispatch, and each row reports the
-p50/p99/p999 enqueue→verdict latency, shed rate, batch fill and
+p50/p99/p999 admission→delivery latency, shed rate, batch fill and
 backpressure occupancy.
 
 Row identity is machine-independent by construction (fixed arrival

@@ -47,6 +47,7 @@ from ..dictionary import OPEN_NBYTES
 from ..events import (CLOSE, OPEN, SEG_SENTINEL, ByteBatch, EventBatch,
                       EventStream, SegmentPack, pack_segments)
 from ..nfa import NFA, WILD_TAG, pad_states
+from ..spans import span
 from . import base
 from .result import NO_MATCH, FilterResult, SparseResult
 
@@ -1225,27 +1226,38 @@ class StreamingEngine(base.FilterEngine):
         slot's original batch row (pads are ``-1``, dropped in-kernel).
         Non-kernel engines and oversized caps parse then route through
         :meth:`filter_batch_sparse`, which records its own path.
+
+        The host's share is timed by spans (:mod:`repro.core.spans`)
+        into ``meta``: ``launch_s`` (staging, H2D and the enqueue),
+        ``device_s`` (blocking on the kernel and reading the match
+        buffer back) and ``expand_s`` (class hits to subscribers).
         """
         b = bb.batch_size
         cap = self.match_cap(b, self.n_queries, match_cap)
         if not (self._fused_bytes_on() and self._fused_sparse_ok(cap)):
             return super().filter_bytes_sparse(bb, bucket=bucket,
                                                match_cap=match_cap)
-        data, starts, spk = self._bytes_prep(bb, pack)
-        doc_map = (spk.doc_ids if spk is not None
-                   else np.arange(b, dtype=np.int32)[:, None])
-        lane_cls, offsets, members = self._plain_lane_tables(self.plan_)
-        buf, cnt = _run_bytes_fused_sparse(
-            self.plan_, data, starts, jnp.asarray(doc_map),
-            jnp.asarray(lane_cls), cap,
-            interpret=self._kernel_interpret())
-        bufs, n, over = _device_rows(buf, cnt, cap)
-        return self._expand_class_hits(
-            bufs, n, cap, offsets, members, batch_size=b,
-            n_queries=self.n_queries, live_ids=None,
-            meta={"path": "kernel-fused", "launch": "bytes"},
-            overflowed=over,
-            dense_fallback=lambda: self.filter_bytes(bb, pack=pack))
+        spans: dict = {}
+        with span("xf.launch", spans):
+            data, starts, spk = self._bytes_prep(bb, pack)
+            doc_map = (spk.doc_ids if spk is not None
+                       else np.arange(b, dtype=np.int32)[:, None])
+            lane_cls, offsets, members = self._plain_lane_tables(self.plan_)
+            buf, cnt = _run_bytes_fused_sparse(
+                self.plan_, data, starts, jnp.asarray(doc_map),
+                jnp.asarray(lane_cls), cap,
+                interpret=self._kernel_interpret())
+        with span("xf.device", spans):
+            bufs, n, over = _device_rows(buf, cnt, cap)
+        with span("xf.expand", spans):
+            sp = self._expand_class_hits(
+                bufs, n, cap, offsets, members, batch_size=b,
+                n_queries=self.n_queries, live_ids=None,
+                meta={"path": "kernel-fused", "launch": "bytes"},
+                overflowed=over,
+                dense_fallback=lambda: self.filter_bytes(bb, pack=pack))
+        sp.meta.update(spans)
+        return sp
 
     def filter_bytes_sharded_sparse(self, bb: ByteBatch, sharded, *,
                                     bucket: int | None = None, mesh=None,

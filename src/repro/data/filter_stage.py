@@ -40,9 +40,13 @@ from ..core.engines import FilterResult, SparseResult
 from ..core.events import (ByteBatch, EventBatch, EventStream,
                            event_stream_nbytes)
 from ..core.nfa import NFA, compile_queries
+from ..core.spans import span
 from ..core.xpath import Query, parse
 
 TEXT_FILL = 8  # filler text bytes per element in the MB/s accounting
+#: host spans of one batch's worker path (:mod:`repro.core.spans`), carried
+#: in a sparse result's ``meta`` and summed into ``stats`` by ``_record``
+SPAN_KEYS = ("pack_s", "launch_s", "device_s", "expand_s")
 
 
 @dataclass
@@ -205,6 +209,7 @@ class FilterStage:
                       "seconds": 0.0, "pair_matches": 0, "pairs": 0,
                       "put_seconds": 0.0, "overlapped_batches": 0,
                       "verdict_bytes": 0, "rebalances": 0,
+                      **dict.fromkeys(SPAN_KEYS, 0.0),
                       # sparse batches per engine route (``meta["path"]``)
                       "verdict_paths": {}}
         # plan epoch: bumped on every committed plan change; the mutex
@@ -446,6 +451,8 @@ class FilterStage:
             paths = self.stats["verdict_paths"]
             path = res.meta.get("path")
             paths[path] = paths.get(path, 0) + 1
+            for k in SPAN_KEYS:
+                self.stats[k] += res.meta.get(k, 0.0)
         else:
             self.stats["pair_matches"] += int(res.matched.sum())
             self.stats["pairs"] += res.matched.size
@@ -457,10 +464,14 @@ class FilterStage:
         verdicts out, parsed on device by ``engine.filter_bytes`` — no
         per-event host Python between payload and verdict.  ``epoch``
         pins the batch to a :meth:`plan_epoch` snapshot so a concurrent
-        plan swap cannot tear engine/plan/gids mid-batch."""
+        plan swap cannot tear engine/plan/gids mid-batch.  The packing
+        is the ``xf.pack`` span; a sparse result carries it in ``meta``
+        beside the engine's own spans."""
         eng = self._eng if epoch is None else epoch.eng
         sharded = self.sharded_ if epoch is None else epoch.sharded
-        bb = ByteBatch.from_buffers(bufs, bucket=self.byte_bucket)
+        spans: dict = {}
+        with span("xf.pack", spans):
+            bb = ByteBatch.from_buffers(bufs, bucket=self.byte_bucket)
         t0 = time.perf_counter()
         if self.data_shards > 1:
             res = eng.filter_bytes_sharded2d(bb, sharded,
@@ -479,6 +490,8 @@ class FilterStage:
         else:
             res = eng.filter_bytes(bb, bucket=self.bucket)
         dt = time.perf_counter() - t0
+        if isinstance(res, SparseResult):
+            res.meta.update(spans)
         if record:
             self._record(res, bb.batch_size, bb.nbytes_total(), dt)
         return res

@@ -489,6 +489,7 @@ def stream_filter_pallas(events: jax.Array, tagmask: jax.Array,
             pltpu.SemaphoreType.DMA((1,)),
         ],
         interpret=interpret,
+        name="stream_filter_pallas",
     )(n_ev, ev, *tabs)
     return _lane_out(matched, dims["qb"]), _lane_out(first, dims["qb"])
 
@@ -560,6 +561,7 @@ def stream_filter_pallas_sparse(events: jax.Array, doc_ids: jax.Array,
             pltpu.SemaphoreType.DMA((1,)),
         ],
         interpret=interpret,
+        name="stream_filter_pallas_sparse",
     )(n_ev, doc_ids.reshape(-1).astype(jnp.int32), ev, *tabs)
     return _match_list(out, int(cap)), cnt
 
@@ -789,6 +791,7 @@ def stream_filter_bytes_pallas(data: jax.Array, starts: jax.Array,
         scratch_shapes=_bytes_scratch(max_depth, n_docs, qr, rows)
         + [pltpu.SemaphoreType.DMA((1,))],
         interpret=interpret,
+        name="stream_filter_bytes_pallas",
     )(ends, starts.reshape(-1).astype(jnp.int32), words, *tabs)
     return _lane_out(matched, dims["qb"]), _lane_out(first, dims["qb"])
 
@@ -859,6 +862,7 @@ def stream_filter_bytes_pallas_sparse(data: jax.Array, starts: jax.Array,
             pltpu.SemaphoreType.DMA((1,)),
         ],
         interpret=interpret,
+        name="stream_filter_bytes_pallas_sparse",
     )(ends, starts.reshape(-1).astype(jnp.int32),
       doc_map.reshape(-1).astype(jnp.int32), words, *tabs)
     return _match_list(out, int(cap)), cnt

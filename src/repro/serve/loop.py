@@ -39,10 +39,19 @@ Dataflow (one :class:`ServeLoop` instance)::
   Verdicts are bit-identical to the synchronous
   :meth:`~repro.data.filter_stage.FilterStage.route_bytes` path —
   batching and pipelining are schedule, not semantics.
-* **SLOs** — every request is timestamped at admission and at verdict
-  materialization; :meth:`ServeLoop.slo_summary` reports
-  p50/p99/p999 bytes→verdict latency, shed rate, batch fill,
-  close-reason counts, queue depth and backpressure occupancy.
+* **SLOs** — every request is timestamped at admission, when its batch
+  closes, at dispatch, when its match list is on the host and after its
+  delivery; :meth:`ServeLoop.slo_summary` reports p50/p99/p999
+  admission→delivery latency, shed rate, batch fill, close-reason
+  counts, queue depth and backpressure occupancy.
+* **Spans** — the batcher's waits, the worker's packing, launch, device
+  wait and expansion, and the completer's fan-out and delivery are
+  :mod:`repro.core.spans`: each adds its seconds to a counter
+  (``slo_summary()`` for the loop's, ``FilterStage.stats`` for the
+  worker's) and, under a running ``jax.profiler``, writes an
+  ``xf.<name>`` event with ``batch=<dispatch sequence number>`` into the
+  trace beside the device's ops.  Compiles while the loop is open are
+  counted too (``compiles``, ``compile_s``).
 
 Fault tolerance (the loop keeps serving through all of these):
 
@@ -82,8 +91,10 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
+import jax.monitoring
 import numpy as np
 
+from ..core import spans
 from ..core.engines import FilterResult
 from ..core.events import (DEFAULT_MAX_DEPTH, DocumentError, KernelFault,
                            validate_payload)
@@ -91,6 +102,8 @@ from ..data.filter_stage import FilterStage, PlanEpoch, RoutedDocument
 
 #: admission policies: drop the arrival (count it) vs stall the producer
 OVERLOAD_POLICIES = ("shed", "block")
+#: JAX's event for one backend compile (seconds)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclass
@@ -108,13 +121,23 @@ class ServeRequest:
     poison documents, or the raw worker exception when the loop runs
     with ``recover=False``.  Exactly one of ``routed`` / ``error`` /
     ``shed`` describes a finished ticket.
+
+    Stamps on the loop's clock: ``t_submit`` at admission, ``t_close``
+    when its batch closed, ``t_dispatch`` when the batch went to a
+    worker, ``t_verdict`` when the worker had its match list on the
+    host, ``t_delivered`` after the ``deliver`` callback returned.
+    ``done`` is set before the delivery, so ``t_delivered`` may still be
+    ``None`` just after ``done`` fires.
     """
 
     payload: bytes
     t_submit: float
     seq: int = -1
     shed: bool = False
+    t_close: float | None = None
+    t_dispatch: float | None = None
     t_verdict: float | None = None
+    t_delivered: float | None = None
     routed: list[RoutedDocument] | None = None
     error: BaseException | None = None
     done: threading.Event = field(default_factory=threading.Event,
@@ -122,10 +145,11 @@ class ServeRequest:
 
     @property
     def latency_s(self) -> float | None:
-        """Enqueue→verdict seconds (``None`` until resolved / if shed)."""
-        if self.t_verdict is None:
+        """Admission→delivery seconds (``None`` until delivered / if
+        shed)."""
+        if self.t_delivered is None:
             return None
-        return self.t_verdict - self.t_submit
+        return self.t_delivered - self.t_submit
 
     @property
     def failed(self) -> bool:
@@ -235,7 +259,14 @@ class ServeLoop:
                          "backpressure_waits": 0, "max_queue_depth": 0,
                          "rejected": 0, "quarantined": 0, "failed": 0,
                          "retries": 0, "swaps": 0, "swap_rollbacks": 0,
-                         "delivery_errors": 0}
+                         "delivery_errors": 0, "compiles": 0,
+                         # seconds: spans of the batcher and completer,
+                         # admission→dispatch summed over resolved
+                         # requests, and backend compiles
+                         "wait_arrival_s": 0.0, "wait_fill_s": 0.0,
+                         "wait_slot_s": 0.0, "fan_out_s": 0.0,
+                         "deliver_s": 0.0, "queue_s": 0.0,
+                         "compile_s": 0.0}
         self._t_first: float | None = None
         self._t_last: float | None = None
         self._batches_since_rebalance = 0
@@ -246,6 +277,8 @@ class ServeLoop:
         self._reconfig_cv = threading.Condition()
         self._reconfig_q: deque = deque()
 
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_compile)
         self._pool = ThreadPoolExecutor(max_workers=self.max_inflight,
                                         thread_name_prefix="serve-filter")
         self._batcher_t = threading.Thread(target=self._batcher,
@@ -326,21 +359,30 @@ class ServeLoop:
         try:
             while True:
                 with self._lock:
-                    while not self._queue and not self._closing:
-                        self._not_empty.wait()
+                    # the waits are spans summed into the counters,
+                    # under the lock the condition waits re-take
+                    batch = self.counters["batches"]
+                    with spans.span("xf.wait_arrival", self.counters,
+                                    batch):
+                        while not self._queue and not self._closing:
+                            self._not_empty.wait()
                     if not self._queue and self._closing:
                         break
                     # batch opens now; close on size or deadline,
                     # whichever fires first (flush closes immediately)
                     deadline = self._clock() + self.deadline_s
-                    while (len(self._queue) < self.max_batch
-                           and not self._closing):
-                        left = deadline - self._clock()
-                        if left <= 0:
-                            break
-                        self._not_empty.wait(timeout=left)
+                    with spans.span("xf.wait_fill", self.counters, batch):
+                        while (len(self._queue) < self.max_batch
+                               and not self._closing):
+                            left = deadline - self._clock()
+                            if left <= 0:
+                                break
+                            self._not_empty.wait(timeout=left)
                     n = min(self.max_batch, len(self._queue))
                     reqs = [self._queue.popleft() for _ in range(n)]
+                    t_close = self._clock()
+                    for r in reqs:
+                        r.t_close = t_close
                     if n == self.max_batch:
                         self.counters["size_closes"] += 1
                     elif self._closing:
@@ -349,7 +391,7 @@ class ServeLoop:
                         self.counters["deadline_closes"] += 1
                     self.counters["batches"] += 1
                     self._not_full.notify_all()
-                self._dispatch(reqs)
+                self._dispatch(reqs, batch)
         except BaseException as e:  # pragma: no cover - defensive
             self._fail(e)
         finally:
@@ -357,21 +399,28 @@ class ServeLoop:
                 self._completion.append(None)
                 self._comp_cv.notify()
 
-    def _dispatch(self, reqs: list[ServeRequest]) -> None:
+    def _dispatch(self, reqs: list[ServeRequest], batch: int) -> None:
         """Take an in-flight slot (counting the wait as backpressure)
         and hand the batch to a worker; completion order is dispatch
         order regardless of which worker finishes first."""
         if not self._slots.acquire(blocking=False):
             with self._lock:
                 self.counters["backpressure_waits"] += 1
-            self._slots.acquire()
+            waited: dict = {}
+            with spans.span("xf.wait_slot", waited, batch):
+                self._slots.acquire()
+            with self._lock:
+                self.counters["wait_slot_s"] += waited["wait_slot_s"]
+        t_dispatch = self._clock()
+        for r in reqs:
+            r.t_dispatch = t_dispatch
         future = self._pool.submit(self._run_batch,
-                                   [r.payload for r in reqs])
+                                   [r.payload for r in reqs], batch)
         with self._comp_cv:
-            self._completion.append((reqs, future))
+            self._completion.append((reqs, future, batch))
             self._comp_cv.notify()
 
-    def _run_batch(self, payloads: list[bytes]):
+    def _run_batch(self, payloads: list[bytes], batch: int | None = None):
         """Worker-thread body: the stage's device bytes→verdict call.
 
         The batch is pinned to a :meth:`FilterStage.plan_epoch`
@@ -379,19 +428,26 @@ class ServeLoop:
         engine/plan/gids — and the snapshot rides along for the
         epoch-consistent fan-out.  ``record=False`` — stage stats are
         mutated only by the single-threaded completer, so K concurrent
-        workers never race the accounting dict.
+        workers never race the accounting dict.  ``batch`` names the
+        spans the stage and engine open on this thread (the completer's
+        re-runs keep the id it set); the clock reading once the match
+        list is on the host is the batch's verdict time.
         """
         t0 = time.perf_counter()
+        if batch is not None:
+            spans.BATCH.set(batch)
         n = len(payloads)
         padded = payloads
         if self.pad_batches and n < self.max_batch:
             padded = payloads + [payloads[-1]] * (self.max_batch - n)
         ep = self.stage.plan_epoch()
         res = self.stage._filter_bytebatch(padded, record=False, epoch=ep)
+        t_verdict = self._clock()
         if len(padded) != n:
             res = FilterResult(res.matched[:n], res.first_event[:n],
                                res.live)
-        return res, [len(p) for p in payloads], time.perf_counter() - t0, ep
+        return (res, [len(p) for p in payloads], time.perf_counter() - t0,
+                ep, t_verdict)
 
     # ----------------------------------------------------------- delivery
     def _completer(self) -> None:
@@ -414,9 +470,12 @@ class ServeLoop:
                 if item[0] == "swap":
                     self._commit_swap(item[1], item[2], item[3])
                     continue
-                reqs, future = item
+                reqs, future, batch = item
+                # the completer's spans, and its re-runs of a failed
+                # batch, carry the batch's id
+                spans.BATCH.set(batch)
                 try:
-                    res, nbytes, dt, ep = future.result()
+                    out = future.result()
                 except BaseException as e:
                     # once the device path itself has failed, later
                     # batches fail fast instead of bisecting again
@@ -425,44 +484,52 @@ class ServeLoop:
                     else:
                         self._fail_requests(reqs, e)
                 else:
-                    self._resolve(reqs, res, nbytes, dt, ep)
+                    self._resolve(reqs, *out)
                 self._slots.release()
                 self._maybe_auto_rebalance()
         except BaseException as e:  # pragma: no cover - defensive
             self._fail(e)
 
     def _resolve(self, reqs: list[ServeRequest], res, nbytes: list[int],
-                 dt: float, ep: PlanEpoch) -> None:
-        """Fan a finished batch's verdicts out to its tickets.
+                 dt: float, ep: PlanEpoch, t_verdict: float) -> None:
+        """Fan a finished batch's verdicts out to its tickets, then
+        deliver them; latency runs from admission to the delivery's
+        return.
 
         Routing uses the epoch the batch was *filtered* under
         (``ep.gids``) and the requests' own seqs — recovered subsets
         are non-contiguous, and a plan swapped after dispatch must not
         remap this batch's verdict columns."""
-        t_done = self._clock()
-        routed = self.stage._fan_out(res, nbytes, gids=ep.gids,
-                                     seqs=[r.seq for r in reqs])
-        self.stage._record(res, len(reqs), sum(nbytes), dt)
+        c = self.counters
+        with spans.span("xf.fan_out", c):
+            routed = self.stage._fan_out(res, nbytes, gids=ep.gids,
+                                         seqs=[r.seq for r in reqs])
+            self.stage._record(res, len(reqs), sum(nbytes), dt)
         by_doc: dict[int, list[RoutedDocument]] = {}
         for rd in routed:
             by_doc.setdefault(rd.doc_index, []).append(rd)
         for r in reqs:
-            r.t_verdict = t_done
+            r.t_verdict = t_verdict
             r.routed = by_doc.get(r.seq, [])
-            self._latencies.append(t_done - r.t_submit)
+            c["queue_s"] += r.t_dispatch - r.t_submit
             r.done.set()
-        self.counters["completed"] += len(reqs)
+        c["completed"] += len(reqs)
         self._last_good = reqs[-1].payload
-        self._t_last = t_done
         self._batch_fills.append(len(reqs) / self.max_batch)
         if self.deliver is not None:
             # a stalled consumer stalls HERE, holding the slot: that is
             # the backpressure chain's first link.  A *raising* consumer
             # must not kill the loop — its error is counted, not fatal.
-            try:
-                self.deliver(routed)
-            except BaseException:
-                self.counters["delivery_errors"] += 1
+            with spans.span("xf.deliver", c):
+                try:
+                    self.deliver(routed)
+                except BaseException:
+                    c["delivery_errors"] += 1
+        t_delivered = self._clock()
+        for r in reqs:
+            r.t_delivered = t_delivered
+            self._latencies.append(t_delivered - r.t_submit)
+        self._t_last = t_delivered
 
     # ------------------------------------------------- failure containment
     def _recover(self, reqs: list[ServeRequest], err: BaseException) -> None:
@@ -510,7 +577,7 @@ class ServeLoop:
         ``suspects`` as ``(request, error)``.  Returns whether any part
         of the subset was served."""
         try:
-            res, nbytes, dt, ep = self._run_batch([r.payload for r in reqs])
+            out = self._run_batch([r.payload for r in reqs])
         except Exception as e:
             if _names_documents(e):
                 return self._isolate(reqs, e, suspects)
@@ -520,7 +587,7 @@ class ServeLoop:
             mid = len(reqs) // 2
             left = self._try_subset(reqs[:mid], suspects)
             return self._try_subset(reqs[mid:], suspects) or left
-        self._resolve(reqs, res, nbytes, dt, ep)
+        self._resolve(reqs, *out)
         return True
 
     def _control_ok(self) -> bool:
@@ -711,6 +778,7 @@ class ServeLoop:
         self._builder_t.join()
         self._completer_t.join()
         self._pool.shutdown(wait=True)
+        jax.monitoring.unregister_event_duration_listener(self._on_compile)
         if self._error is not None:
             err, self._error = self._error, None
             raise err
@@ -721,11 +789,27 @@ class ServeLoop:
     def __exit__(self, exc_type, exc, tb) -> None:
         self.close()
 
+    def _on_compile(self, event: str, duration_s: float, **_kw) -> None:
+        """``jax.monitoring`` listener: a backend compile, on whatever
+        thread compiled, while the loop is open."""
+        if event == COMPILE_EVENT:
+            with self._lock:
+                self.counters["compiles"] += 1
+                self.counters["compile_s"] += duration_s
+
     # ------------------------------------------------------------ metrics
     def slo_summary(self) -> dict:
-        """Latency percentiles + occupancy counters for everything
-        served so far (ms; ``nan`` percentiles until something
-        completes).
+        """Admission→delivery latency percentiles + occupancy
+        counters for everything served so far (ms; ``nan`` percentiles
+        until something is delivered).
+
+        Seconds summed by the spans: ``wait_arrival_s`` (batcher, queue
+        empty), ``wait_fill_s`` (batch open, waiting for size or
+        deadline), ``wait_slot_s`` (all in-flight slots taken),
+        ``fan_out_s`` (completer: fan-out and the stage's accounting),
+        ``deliver_s`` (the ``deliver`` callback); ``queue_s`` is
+        admission→dispatch summed over resolved requests; ``compiles`` /
+        ``compile_s`` count backend compiles while the loop is open.
 
         Accounting closes even under failures: every arrival ends in
         exactly one of completed / shed / failed / quarantined, so at
@@ -769,7 +853,8 @@ class ServeLoop:
         }
 
     def latencies_ms(self) -> np.ndarray:
-        """Per-request enqueue→verdict latencies (ms), completion order."""
+        """Per-request admission→delivery latencies (ms), delivery
+        order."""
         return np.asarray(self._latencies) * 1e3
 
     def latency_histogram(self, n_bins: int = 32) -> dict:

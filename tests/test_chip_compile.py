@@ -13,6 +13,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,3 +113,17 @@ def test_compiles_for_v5e(name, plan, one_chip):
     plan_, cap = plan
     compiled = _program(name, plan_, cap, one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("bytes-sparse", "stream_filter_bytes_pallas_sparse"),
+    ("bytes-dense", "stream_filter_bytes_pallas"),
+    ("events-sparse", "stream_filter_pallas_sparse"),
+    ("events-dense", "stream_filter_pallas")])
+def test_megakernel_keeps_its_name(name, kernel, plan, one_chip):
+    """The trace names a kernel's ``XLA Ops`` event after the custom
+    call, whose name is the ``pallas_call``'s own ``name=``: the
+    benchmark's trace reduction finds the kernel by it."""
+    plan_, cap = plan
+    text = _program(name, plan_, cap, one_chip).compile().as_text()
+    assert re.search(rf"%{kernel}(\.\d+)? = .* custom-call\(", text)
