@@ -334,14 +334,23 @@ def _device_rows(buf, cnt, cap: int, ndev: int = 1
     ``shard_map`` concatenates each device's bounded buffer along the
     leading axis; only the first ``min(count_d, cap)`` rows of each are
     real.  Returns ``((docs, cls, first), total_count, overflowed)``
-    where overflow means ANY device saturated its buffer.
+    where overflow means ANY device saturated its buffer.  A count row
+    may carry more columns (:func:`_tag_starts`); the first is the
+    match count.
     """
     buf = np.asarray(buf).reshape(ndev, -1, 3)
-    cnt = np.asarray(cnt).reshape(ndev)
+    cnt = np.asarray(cnt).reshape(ndev, -1)[:, 0]
     rows = np.concatenate(
         [buf[dv, :min(int(c), cap)] for dv, c in enumerate(cnt)])
     return ((rows[:, 0], rows[:, 1], rows[:, 2]),
             int(cnt.sum()), bool((cnt > int(cap)).any()))
+
+
+def _tag_starts(cnt) -> int:
+    """Tag starts the bytes kernels walked, summed over devices: the
+    second column of the count rows :func:`_device_rows` has read back
+    (a host copy already made, so no further sync)."""
+    return int(np.asarray(cnt).reshape(-1, 2)[:, 1].sum())
 
 
 def _lane_classes(plan: base.FilterPlan) -> tuple[np.ndarray, np.ndarray]:
@@ -1253,7 +1262,8 @@ class StreamingEngine(base.FilterEngine):
             sp = self._expand_class_hits(
                 bufs, n, cap, offsets, members, batch_size=b,
                 n_queries=self.n_queries, live_ids=None,
-                meta={"path": "kernel-fused", "launch": "bytes"},
+                meta={"path": "kernel-fused", "launch": "bytes",
+                      "tag_starts": _tag_starts(cnt)},
                 overflowed=over,
                 dense_fallback=lambda: self.filter_bytes(bb, pack=pack))
         sp.meta.update(spans)
@@ -1309,7 +1319,8 @@ class StreamingEngine(base.FilterEngine):
         return self._expand_class_hits(
             bufs, n, cap, offsets, members, batch_size=b,
             n_queries=len(live_ids), live_ids=live_ids,
-            meta={"path": "kernel-fused", "launch": "bytes"},
+            meta={"path": "kernel-fused", "launch": "bytes",
+                  "tag_starts": _tag_starts(cnt)},
             overflowed=over,
             dense_fallback=lambda: self.filter_bytes_sharded(
                 bb, sharded, mesh=mesh))

@@ -726,13 +726,32 @@ def encode_bytes(ev: EventStream, text_fill: int = 0) -> bytes:
     return bytes(out)
 
 
+def _markers(b: np.ndarray, sym_table: np.ndarray
+             ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Every ``<`` of the bytes ``b``: ``(offset, is_close, ok, tag)``.
+
+    A tag can start only at a ``<``, so the pre-decoder's classification
+    is computed there alone: ``</`` closes, anything else opens, and the
+    marker is valid (``ok``) when both symbol bytes after it are in the
+    64-symbol alphabet.  Past the end the kernel shifts in zeros, and
+    byte 0 is not in the alphabet, so a marker cut off by the end is
+    invalid here too.
+    """
+    at = np.flatnonzero(b == LT)
+    b = np.concatenate([b, np.zeros(3, np.uint8)])
+    is_close = b[at + 1] == SLASH
+    s0 = at + 1 + is_close
+    v0, v1 = sym_table[b[s0]], sym_table[b[s0 + 1]]
+    return at, is_close, (v0 >= 0) & (v1 >= 0), (v0 << 6) | v1
+
+
 def decode_bytes(buf: bytes, sym_table: np.ndarray) -> EventStream:
     """Byte stream → event stream (host reference for the predecode kernel).
 
-    Vectorised with numpy the same way the Pallas kernel does it on-device:
-    classify each byte position, then decode the two symbol bytes that follow
-    each ``<`` / ``</`` marker.  Fixed-length tags (the paper's dictionary
-    replacement) are what make this embarrassingly parallel.
+    Vectorised with numpy: find every ``<``, then decode the two symbol
+    bytes that follow each ``<`` / ``</`` marker.  Fixed-length tags (the
+    paper's dictionary replacement) are what make this embarrassingly
+    parallel.
 
     A ``<`` / ``</`` marker whose symbol bytes are not both in the
     64-symbol alphabet is *rejected* (no event emitted) — identical to
@@ -740,27 +759,20 @@ def decode_bytes(buf: bytes, sym_table: np.ndarray) -> EventStream:
     :mod:`repro.kernels.predecode`, so host and device agree on
     malformed input.
     """
-    b = np.frombuffer(buf, dtype=np.uint8)
-    n = b.shape[0]
-    if n == 0:
-        return EventStream(np.zeros(0, np.int8), np.zeros(0, np.int32))
-    is_lt = b == LT
-    nxt = np.concatenate([b[1:], np.zeros(1, np.uint8)])
-    is_close = is_lt & (nxt == SLASH)
-    is_open = is_lt & ~is_close
-    # symbol positions: open '<' at i → symbols at i+1, i+2 ; close at i+2, i+3
-    idx = np.arange(n)
-    s0 = np.where(is_close, idx + 2, idx + 1)
-    s1 = s0 + 1
-    # the kernel shifts zeros in past the end; byte 0 is not in the
-    # alphabet, so out-of-range symbol positions are invalid there too
-    v0 = np.where(s0 < n, sym_table[b[np.clip(s0, 0, n - 1)]], -1)
-    v1 = np.where(s1 < n, sym_table[b[np.clip(s1, 0, n - 1)]], -1)
-    ok = (v0 >= 0) & (v1 >= 0)
-    tag = (v0 << 6) | v1
-    keep = (is_open | is_close) & ok
-    kind = np.where(is_close[keep], CLOSE, OPEN).astype(np.int8)
-    return EventStream(kind, tag[keep].astype(np.int32))
+    _, kind, tag = decode_tags(buf, sym_table)
+    return EventStream(kind, tag)
+
+
+def decode_tags(buf: bytes, sym_table: np.ndarray
+                ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Byte stream → ``(offset, kind, tag)`` of every tag that starts in
+    it, in order: :func:`decode_bytes` with the byte offset of each
+    event's ``<`` (the host reference for the bytes kernel's tag-start
+    bitmap, :func:`repro.kernels.stream_filter.tag_bitmap`)."""
+    at, is_close, ok, tag = _markers(np.frombuffer(buf, np.uint8),
+                                     sym_table)
+    kind = np.where(is_close[ok], CLOSE, OPEN).astype(np.int8)
+    return at[ok], kind, tag[ok].astype(np.int32)
 
 
 _SYM_TABLE: np.ndarray | None = None
@@ -781,8 +793,9 @@ def validate_payload(buf: bytes, *, max_depth: int = DEFAULT_MAX_DEPTH,
     The serve loop's first failure domain (:meth:`repro.serve.loop.
     ServeLoop.submit`): known-bad bytes are rejected with a typed
     :class:`DocumentError` *before* they are batched with healthy
-    documents or reach a kernel.  Vectorized numpy only — a handful of
-    cumsums over the byte buffer, no per-event Python:
+    documents or reach a kernel.  Vectorized numpy only — one compare
+    over the byte buffer to find the ``<`` markers, then work per
+    marker, no per-event Python:
 
     * a ``<`` / ``</`` marker whose symbol bytes are outside the
       64-symbol alphabet (the kernel would silently drop it, skewing
@@ -798,29 +811,12 @@ def validate_payload(buf: bytes, *, max_depth: int = DEFAULT_MAX_DEPTH,
     this function admits, the device parser handles deterministically.
     """
     idx = () if doc_index is None else (doc_index,)
-    b = np.frombuffer(buf, dtype=np.uint8)
-    n = b.shape[0]
-    if n == 0:
-        return
-    sym = _sym_table()
-    is_lt = b == LT
-    nxt = np.concatenate([b[1:], np.zeros(1, np.uint8)])
-    is_close = is_lt & (nxt == SLASH)
-    is_open = is_lt & ~is_close
-    pos = np.arange(n)
-    s0 = np.where(is_close, pos + 2, pos + 1)
-    s1 = s0 + 1
-    v0 = np.where(s0 < n, sym[b[np.clip(s0, 0, n - 1)]], -1)
-    v1 = np.where(s1 < n, sym[b[np.clip(s1, 0, n - 1)]], -1)
-    ok = (v0 >= 0) & (v1 >= 0)
-    marker = is_open | is_close
-    bad = marker & ~ok
-    if bad.any():
-        where = int(np.flatnonzero(bad)[0])
+    at, is_close, ok, _ = _markers(np.frombuffer(buf, np.uint8),
+                                   _sym_table())
+    if not ok.all():
         raise MalformedDocument(
-            f"undecodable tag marker at byte {where}", idx)
-    delta = np.where(is_open & ok, 1, 0) - np.where(is_close & ok, 1, 0)
-    depth = np.cumsum(delta)
+            f"undecodable tag marker at byte {int(at[~ok][0])}", idx)
+    depth = np.cumsum(np.where(is_close, -1, 1))
     if depth.min(initial=0) < 0:
         raise MalformedDocument("close tag without matching open", idx)
     if depth.size and depth[-1] != 0:

@@ -28,9 +28,13 @@ Layout (see the README "Kernel hot path" diagram):
 * **events stream through SMEM chunks** — each document's fused
   ``(kind<<16)|tag`` event words (or raw wire bytes, for the one-launch
   bytes kernel) arrive as a lane-dense VMEM block; the kernel copies one
-  ``(8, 128)`` tile at a time into SMEM and walks it with the scalar
-  core (the "8-bit streaming interface" of Fig 3), stopping at the
-  document's last real event or byte.
+  chunk of ``(8, 128)`` tiles at a time into SMEM and walks it with the
+  scalar core (the "8-bit streaming interface" of Fig 3), stopping at
+  the document's last real event or byte.  The bytes kernel's scalar
+  core stops only where a tag starts: every byte position is classified
+  beforehand on the vector side into a tag-start bitmap
+  (:func:`tag_bitmap`, one bit per byte), and the walk visits the
+  bitmap's words and, in each, its set bits.
 
 Per-block tables are stored by :mod:`repro.kernels.blocks` in their
 canonical packed form (``(G, WB)`` words, ``(G, WB, 32)`` parent
@@ -567,22 +571,27 @@ def stream_filter_pallas_sparse(events: jax.Array, doc_ids: jax.Array,
 
 
 # ----------------------------------------------- one-launch bytes kernel
-def _bytes_stream(data_ref, n_bytes, starts_ref, st0, stack_ref, mbuf_ref,
-                  fbuf_ref, bbuf_ref, sem_ref, tb, init_row, *,
-                  max_depth: int, n_tags: int, qr: int):
+def _bytes_stream(data_ref, bm_ref, n_bytes, starts_ref, st0, stack_ref,
+                  mbuf_ref, fbuf_ref, bbuf_ref, bmbuf_ref, sem_ref, tb,
+                  init_row, *, max_depth: int, n_tags: int, qr: int):
     """Streaming body of the one-launch bytes kernel, one grid cell.
 
     Shared verbatim by the dense (:func:`_bytes_kernel`) and
     fused-sparse (:func:`_bytes_kernel_sparse`) launch shapes.  Per chunk
     of bytes: copy the int32-packed bytes VMEM→SMEM (the chunk plus one
-    lookahead tile, so tags straddling the boundary decode whole), and
-    walk every position on the scalar core — the FPGA's
-    byte-serial streaming interface.  A ``<`` runs the §3.4 character
-    pre-decode (:func:`repro.kernels.parse.fused_predecode`, the same
-    function the vector parser uses) on it and its three lookahead
-    bytes; a valid tag becomes one event through the shared
-    :func:`_advance` transition.  The walk stops at the segment's last
-    non-zero byte (``n_bytes``): zero padding starts no event.
+    lookahead tile, so tags straddling the boundary decode whole) and
+    the chunk's words of the tag-start bitmap (:func:`tag_bitmap`, one
+    bit per byte, made on the vector side before the launch).  The
+    scalar core walks the bitmap words, not the bytes: an empty word is
+    one load and one compare; a non-empty one gives up its set bits
+    lowest first (``w & -w`` isolates one, ``w & (w - 1)`` clears it;
+    bit 31 makes the word negative, which neither minds).  Each set bit
+    is a tag start: the §3.4 character pre-decode
+    (:func:`repro.kernels.parse.fused_predecode`, the same function the
+    vector parser uses) runs on its byte and three lookahead bytes, and
+    the tag becomes one event through the shared :func:`_advance`
+    transition.  The walk stops at the segment's last non-zero byte
+    (``n_bytes``): zero padding starts no tag.
 
     The ``starts`` table (one boundary row per segment at flat offset
     ``st0``, INT32_MAX sentinel past the last doc) drives per-document
@@ -600,6 +609,7 @@ def _bytes_stream(data_ref, n_bytes, starts_ref, st0, stack_ref, mbuf_ref,
 
     rows = bbuf_ref.shape[0] - TILE_ROWS
     chunk = rows * ROW_BYTES
+    bm_rows = bmbuf_ref.shape[0]
 
     def byte_at(q):
         # byte q of the staged int32 words, little-endian
@@ -610,6 +620,9 @@ def _bytes_stream(data_ref, n_bytes, starts_ref, st0, stack_ref, mbuf_ref,
         _to_smem(data_ref.at[0, pl.ds(pl.multiple_of(ci * rows, TILE_ROWS),
                                       rows + TILE_ROWS), :],
                  bbuf_ref, sem_ref)
+        _to_smem(bm_ref.at[0, pl.ds(pl.multiple_of(ci * bm_rows, TILE_ROWS),
+                                    bm_rows), :],
+                 bmbuf_ref, sem_ref)
         base = ci * chunk
 
         def on_event(p, fused, carry):
@@ -636,18 +649,22 @@ def _bytes_stream(data_ref, n_bytes, starts_ref, st0, stack_ref, mbuf_ref,
                 max_depth=max_depth, n_tags=n_tags)
             return d, nxt, depth, doc0, ord_ + 1, matched, first
 
-        def byte_body(p, carry):
-            def on_lt(carry):
-                fused, keep = parse_mod.fused_predecode(
-                    *(byte_at(p + k) for k in range(4)))
-                return jax.lax.cond(keep, functools.partial(
-                    on_event, p, fused), lambda c: c, carry)
+        def word_body(k, carry):
+            def bit_body(c):
+                w, carry = c[0], c[1:]
+                # the lowest set bit's index: 31 - clz of ``w & -w``
+                p = (k << 5) + 31 - jax.lax.clz(w & -w)
+                fused, _ = parse_mod.fused_predecode(
+                    *(byte_at(p + i) for i in range(4)))
+                return (w & (w - 1),) + on_event(p, fused, carry)
 
-            return jax.lax.cond(byte_at(p) == ref._LT, on_lt,
-                                lambda c: c, carry)
+            return jax.lax.while_loop(
+                lambda c: c[0] != 0, bit_body,
+                (bmbuf_ref[k >> 7, k & (LANES - 1)],) + carry)[1:]
 
         return jax.lax.fori_loop(
-            0, jnp.minimum(chunk, n_bytes - base), byte_body, carry)
+            0, jnp.minimum(chunk, n_bytes - base + 31) >> 5, word_body,
+            carry)
 
     d, _, _, _, _, matched, first = jax.lax.fori_loop(
         0, (n_bytes + chunk - 1) // chunk, chunk_body,
@@ -659,31 +676,32 @@ def _bytes_stream(data_ref, n_bytes, starts_ref, st0, stack_ref, mbuf_ref,
     fbuf_ref[d] = first
 
 
-def _bytes_kernel(n_bytes_ref, starts_ref, data_ref, tagmask_ref, pw_ref,
-                  pb_ref, self_ref, init_ref, accw_ref, accb_ref,
+def _bytes_kernel(n_bytes_ref, starts_ref, data_ref, bm_ref, tagmask_ref,
+                  pw_ref, pb_ref, self_ref, init_ref, accw_ref, accb_ref,
                   matched_ref, first_ref, stack_ref, mbuf_ref, fbuf_ref,
-                  bbuf_ref, sem_ref, *, max_depth: int, n_tags: int,
-                  n_docs: int, doc_axis: int):
+                  bbuf_ref, bmbuf_ref, sem_ref, *, max_depth: int,
+                  n_tags: int, n_docs: int, doc_axis: int):
     """One-launch bytes→verdict (dense): stream, then copy the per-doc
     accept-lane rows out (see :func:`_bytes_stream`)."""
     s = pl.program_id(doc_axis)
     qr = accw_ref.shape[0]
     tb = _block_tables(tagmask_ref, pw_ref, pb_ref, self_ref, accw_ref,
                        accb_ref)
-    _bytes_stream(data_ref, n_bytes_ref[s], starts_ref, s * (n_docs + 1),
-                  stack_ref, mbuf_ref, fbuf_ref, bbuf_ref, sem_ref, tb,
-                  init_ref[...], max_depth=max_depth, n_tags=n_tags, qr=qr)
+    _bytes_stream(data_ref, bm_ref, n_bytes_ref[s], starts_ref,
+                  s * (n_docs + 1), stack_ref, mbuf_ref, fbuf_ref, bbuf_ref,
+                  bmbuf_ref, sem_ref, tb, init_ref[...],
+                  max_depth=max_depth, n_tags=n_tags, qr=qr)
     matched_ref[...] = mbuf_ref[...]
     first_ref[...] = fbuf_ref[...]
 
 
 def _bytes_kernel_sparse(n_bytes_ref, starts_ref, docmap_ref, data_ref,
-                         tagmask_ref, pw_ref, pb_ref, self_ref, init_ref,
-                         accw_ref, accb_ref, lane_ref, out_ref, cnt_ref,
-                         stack_ref, mbuf_ref, fbuf_ref, bbuf_ref,
-                         stage_ref, stage_sm, sem_ref, *, max_depth: int,
-                         n_tags: int, n_docs: int, doc_axis: int,
-                         cap: int):
+                         bm_ref, tagmask_ref, pw_ref, pb_ref, self_ref,
+                         init_ref, accw_ref, accb_ref, lane_ref, out_ref,
+                         cnt_ref, stack_ref, mbuf_ref, fbuf_ref, bbuf_ref,
+                         bmbuf_ref, stage_ref, stage_sm, sem_ref, *,
+                         max_depth: int, n_tags: int, n_docs: int,
+                         doc_axis: int, cap: int):
     """Sparse twin of :func:`_bytes_kernel`: after the stream, every
     document row of the segment is appended to the shared bounded match
     buffer (``docmap`` names each slot's global batch row; ``-1`` pad
@@ -693,9 +711,10 @@ def _bytes_kernel_sparse(n_bytes_ref, starts_ref, docmap_ref, data_ref,
     _sparse_init(out_ref, cnt_ref)
     tb = _block_tables(tagmask_ref, pw_ref, pb_ref, self_ref, accw_ref,
                        accb_ref)
-    _bytes_stream(data_ref, n_bytes_ref[s], starts_ref, s * (n_docs + 1),
-                  stack_ref, mbuf_ref, fbuf_ref, bbuf_ref, sem_ref, tb,
-                  init_ref[...], max_depth=max_depth, n_tags=n_tags, qr=qr)
+    _bytes_stream(data_ref, bm_ref, n_bytes_ref[s], starts_ref,
+                  s * (n_docs + 1), stack_ref, mbuf_ref, fbuf_ref, bbuf_ref,
+                  bmbuf_ref, sem_ref, tb, init_ref[...],
+                  max_depth=max_depth, n_tags=n_tags, qr=qr)
     cls = lane_ref[...]
 
     def doc_body(dd, carry):
@@ -707,23 +726,69 @@ def _bytes_kernel_sparse(n_bytes_ref, starts_ref, docmap_ref, data_ref,
     jax.lax.fori_loop(0, n_docs, doc_body, jnp.int32(0))
 
 
-def _byte_rows(data: jax.Array, rows: int) -> tuple[jax.Array, jax.Array]:
-    """(S, L) uint8 → ((S, R, 128) little-endian int32 words, (S,) ends).
+def tag_bitmap(data: jax.Array) -> jax.Array:
+    """(S, L) uint8 rows → (S, ⌈L/32⌉) int32 tag-start bitmap.
 
-    Rows are padded to whole chunks of ``rows`` rows plus one spare tile
-    of zeros (the lookahead of the last chunk).  ``ends`` is one past
-    each segment's last non-zero byte — where the kernel's walk stops.
+    Bit ``j`` of word ``k`` is set where a valid tag starts at byte
+    ``32k + j``: the positions that the pre-decoder
+    (:func:`repro.kernels.ref.predecode`, bit-identical to
+    :func:`repro.kernels.parse.fused_predecode`) keeps, with zeros
+    shifted in past the row's end.  Every position is classified at
+    once, on the vector side, so the bytes kernel's scalar walk visits
+    only the bytes where a tag starts.
     """
     nseg, length = data.shape
-    npad = (_round_up(length, rows * ROW_BYTES)
-            + TILE_ROWS * ROW_BYTES)
+    data = jnp.pad(data, ((0, 0), (0, -length % 32)))
+    kind, _ = ref.predecode(data)
+    bits = (kind != ref.PAD).astype(jnp.uint32).reshape(nseg, -1, 32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                    dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def _bitmap_rows(rows: int) -> int:
+    """Rows of one chunk's bitmap block: a chunk of ``rows`` byte rows
+    has ``rows * 16`` bitmap words, in whole ``(8, 128)`` tiles."""
+    return _round_up(rows * ROW_BYTES // 32 // LANES, TILE_ROWS)
+
+
+def _byte_rows(data: jax.Array, rows: int
+               ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """(S, L) uint8 → the bytes kernel's inputs.
+
+    Returns ``(words, bitmap, ends, tag_starts)``: ``words`` (S, R, 128)
+    little-endian int32 byte words, the rows padded to whole chunks of
+    ``rows`` rows plus one spare tile of zeros (the lookahead of the
+    last chunk); ``bitmap`` (S, C · bm_rows, 128) the :func:`tag_bitmap`
+    words of each of the C chunks, one block of :func:`_bitmap_rows`
+    rows per chunk; ``ends`` (S,) one past each segment's last non-zero
+    byte — where the kernel's walk stops; ``tag_starts`` the bitmap's
+    set bits, the events the kernel walks per state-word block.
+    """
+    nseg, length = data.shape
+    chunk = rows * ROW_BYTES
+    n_chunks = -(-length // chunk)
+    npad = n_chunks * chunk + TILE_ROWS * ROW_BYTES
     data = jnp.pad(data, ((0, 0), (0, npad - length)))
     pos = jax.lax.broadcasted_iota(jnp.int32, data.shape, 1)
     ends = jnp.max(jnp.where(data != 0, pos + 1, 0), axis=1)
     words = jax.lax.bitcast_convert_type(
         data.reshape(nseg, npad // 4, 4), jnp.int32)
-    return words.reshape(nseg, npad // (4 * LANES), LANES), \
-        ends.astype(jnp.int32)
+    with jax.named_scope("tag_bitmap"):
+        bm = tag_bitmap(data)[:, :n_chunks * chunk // 32]
+        tag_starts = jnp.sum(jax.lax.population_count(bm))
+        bm = bm.reshape(nseg, n_chunks, -1, LANES)
+        bm = jnp.pad(bm, ((0, 0), (0, 0),
+                          (0, _bitmap_rows(rows) - bm.shape[2]), (0, 0)))
+    return (words.reshape(nseg, npad // (4 * LANES), LANES),
+            bm.reshape(nseg, -1, LANES), ends.astype(jnp.int32), tag_starts)
+
+
+def _byte_specs(by_doc, words: jax.Array, bm: jax.Array) -> list:
+    """BlockSpecs of one segment's byte words and tag-start bitmap."""
+    return [pl.BlockSpec((1,) + x.shape[1:],
+                         lambda *ids: by_doc(*ids) + (0, 0))
+            for x in (words, bm)]
 
 
 def _bytes_scratch(max_depth: int, n_docs: int, qr: int,
@@ -734,6 +799,8 @@ def _bytes_scratch(max_depth: int, n_docs: int, qr: int,
         pltpu.VMEM((n_docs, qr, LANES), jnp.int32),      # first buf
         # one byte chunk + its lookahead tile, as int32 words
         pltpu.SMEM((rows + TILE_ROWS, LANES), jnp.int32),
+        # the chunk's tag-start bitmap words
+        pltpu.SMEM((_bitmap_rows(rows), LANES), jnp.int32),
     ]
 
 
@@ -770,7 +837,7 @@ def stream_filter_bytes_pallas(data: jax.Array, starts: jax.Array,
     tabs, dims = _kernel_tables(tagmask, pw, pb, selfloop_words,
                                 init_words, acc_word, acc_bit)
     rows = _chunk_rows(chunk, ROW_BYTES)
-    words, ends = _byte_rows(data, rows)
+    words, bm, ends, _ = _byte_rows(data, rows)
     g, qr = dims["g"], dims["qr"]
     grid, doc_axis, by_block, by_doc = _grid_maps(grid_order, nseg, g)
     out_spec = pl.BlockSpec(
@@ -782,8 +849,7 @@ def stream_filter_bytes_pallas(data: jax.Array, starts: jax.Array,
                           doc_axis=doc_axis),
         grid=grid,
         in_specs=[_smem_full(), _smem_full(),
-                  pl.BlockSpec((1,) + words.shape[1:],
-                               lambda *ids: by_doc(*ids) + (0, 0))]
+                  *_byte_specs(by_doc, words, bm)]
         + _table_specs(by_block, dims),
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((nseg, g, n_docs, qr, LANES),
@@ -792,7 +858,7 @@ def stream_filter_bytes_pallas(data: jax.Array, starts: jax.Array,
         + [pltpu.SemaphoreType.DMA((1,))],
         interpret=interpret,
         name="stream_filter_bytes_pallas",
-    )(ends, starts.reshape(-1).astype(jnp.int32), words, *tabs)
+    )(ends, starts.reshape(-1).astype(jnp.int32), words, bm, *tabs)
     return _lane_out(matched, dims["qb"]), _lane_out(first, dims["qb"])
 
 
@@ -823,7 +889,9 @@ def stream_filter_bytes_pallas_sparse(data: jax.Array, starts: jax.Array,
     (``SegmentPack.doc_ids``; ``-1`` = unused slot, dropped);
     ``lane_cls`` (G, QB) int32 accept-class names.  Returns
     ``(buf, count)`` with the same validity/overflow contract as the
-    event-stream sparse wrapper.
+    event-stream sparse wrapper, except that ``count`` is ``(1, 2)``:
+    the match count, then the tag starts the scalar walk visited per
+    state-word block (the set bits of :func:`tag_bitmap`).
     """
     from . import interpret_default
 
@@ -834,7 +902,7 @@ def stream_filter_bytes_pallas_sparse(data: jax.Array, starts: jax.Array,
     tabs, dims = _kernel_tables(tagmask, pw, pb, selfloop_words,
                                 init_words, acc_word, acc_bit, lane_cls)
     rows = _chunk_rows(chunk, ROW_BYTES)
-    words, ends = _byte_rows(data, rows)
+    words, bm, ends, tag_starts = _byte_rows(data, rows)
     g, qr = dims["g"], dims["qr"]
     brows = _buffer_rows(cap)
     grid, doc_axis, by_block, by_doc = _grid_maps(grid_order, nseg, g)
@@ -844,8 +912,7 @@ def stream_filter_bytes_pallas_sparse(data: jax.Array, starts: jax.Array,
                           doc_axis=doc_axis, cap=int(cap)),
         grid=grid,
         in_specs=[_smem_full(), _smem_full(), _smem_full(),
-                  pl.BlockSpec((1,) + words.shape[1:],
-                               lambda *ids: by_doc(*ids) + (0, 0))]
+                  *_byte_specs(by_doc, words, bm)]
         + _table_specs(by_block, dims, with_cls=True),
         out_specs=[
             pl.BlockSpec((3, brows, LANES), lambda *ids: (0, 0, 0)),
@@ -864,5 +931,6 @@ def stream_filter_bytes_pallas_sparse(data: jax.Array, starts: jax.Array,
         interpret=interpret,
         name="stream_filter_bytes_pallas_sparse",
     )(ends, starts.reshape(-1).astype(jnp.int32),
-      doc_map.reshape(-1).astype(jnp.int32), words, *tabs)
-    return _match_list(out, int(cap)), cnt
+      doc_map.reshape(-1).astype(jnp.int32), words, bm, *tabs)
+    return (_match_list(out, int(cap)),
+            jnp.concatenate([cnt, tag_starts.reshape(1, 1)], axis=1))

@@ -4,7 +4,9 @@ The streaming megakernels and the pre-decode kernel are compiled for a
 *described* ``v5e:2x2`` topology at the widths ``chip_smoke.py`` serves:
 10,000 profiles over the 24-tag DTD (a ~9,000-state plan of many
 blocks), batches of 64 documents in 68 KB rows, and a match buffer
-that holds every (document, accept state) pair.  Mosaic refuses here
+that holds every (document, accept state) pair.  The bytes kernels
+compile besides at the benchmark's ``xmark-1k`` widths: 81,920-byte
+rows in batches of 16 and 64.  Mosaic refuses here
 what it would refuse on the chip — block shapes off the tiling, gathers
 it cannot lower, more fast memory than a kernel may use — at no chip
 time.  Nothing runs, so this says nothing about results or speed.
@@ -31,6 +33,9 @@ from repro.kernels.predecode import predecode_pallas
 BATCH = 64
 ROW_BYTES = 68 * 1024
 EVENTS = 8192
+#: the ``xmark-1k`` cell's row (``byte_bucket``) and batch sizes
+XMARK_ROW_BYTES = 81_920
+XMARK_BATCHES = (16, 64)
 
 
 @pytest.fixture(scope="module")
@@ -73,25 +78,27 @@ def plan():
     eng = engines.create("streaming", nfa, dictionary=d, kernel="pallas")
     assert eng.plan_.meta["n_blocks"] > 1
     assert eng.plan_.meta["n_states"] > 8000
-    cap = BATCH * int(np.unique(nfa.tables.accept_state).size)
-    return eng.plan_, cap
+    return eng.plan_, int(np.unique(nfa.tables.accept_state).size)
 
 
 def _shape(x, sharding):
     return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
 
-def _program(name, plan, cap, one_chip):
-    """(jitted program, argument shapes) of one served launch."""
+def _program(name, plan, per_doc, one_chip, batch=BATCH,
+             row_bytes=ROW_BYTES):
+    """(jitted program, argument shapes) of one served launch; the
+    match buffer holds ``per_doc`` entries per document."""
     p = jax.tree.map(lambda x: _shape(x, one_chip), plan)
     g, qb = plan["kb_acc_word"].shape
 
     def s(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    data, starts = s((BATCH, ROW_BYTES), jnp.uint8), s((BATCH, 2))
-    ids, lanes = s((BATCH, 1)), s((g, qb))
-    kind, tag = s((BATCH, EVENTS)), s((BATCH, EVENTS))
+    cap = batch * per_doc
+    data, starts = s((batch, row_bytes), jnp.uint8), s((batch, 2))
+    ids, lanes = s((batch, 1)), s((g, qb))
+    kind, tag = s((batch, EVENTS)), s((batch, EVENTS))
     return {
         "bytes-sparse": lambda: streaming._run_bytes_fused_sparse.lower(
             p, data, starts, ids, lanes, cap=cap, interpret=False),
@@ -110,8 +117,19 @@ def _program(name, plan, cap, one_chip):
                                   "events-sparse", "events-dense",
                                   "predecode"])
 def test_compiles_for_v5e(name, plan, one_chip):
-    plan_, cap = plan
-    compiled = _program(name, plan_, cap, one_chip).compile()
+    plan_, per_doc = plan
+    compiled = _program(name, plan_, per_doc, one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch", XMARK_BATCHES)
+@pytest.mark.parametrize("name", ["bytes-sparse", "bytes-dense"])
+def test_bytes_kernels_compile_at_xmark_widths(name, batch, plan, one_chip):
+    """The tag-start bitmap input and the scalar walk over its words, at
+    the rows and batches the benchmark's cells launch."""
+    plan_, per_doc = plan
+    compiled = _program(name, plan_, per_doc, one_chip, batch=batch,
+                        row_bytes=XMARK_ROW_BYTES).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -124,6 +142,6 @@ def test_megakernel_keeps_its_name(name, kernel, plan, one_chip):
     """The trace names a kernel's ``XLA Ops`` event after the custom
     call, whose name is the ``pallas_call``'s own ``name=``: the
     benchmark's trace reduction finds the kernel by it."""
-    plan_, cap = plan
-    text = _program(name, plan_, cap, one_chip).compile().as_text()
+    plan_, per_doc = plan
+    text = _program(name, plan_, per_doc, one_chip).compile().as_text()
     assert re.search(rf"%{kernel}(\.\d+)? = .* custom-call\(", text)

@@ -108,6 +108,44 @@ class TestValidatePayload:
         e = DepthOverflow("deep", (3, 5))
         assert e.doc_indices == (3, 5)
 
+    def test_agrees_with_the_device_predecoder(self):
+        """The check looks at the ``<`` bytes alone; on random payloads
+        it reaches the verdict that the device pre-decoder's per-byte
+        classification (:func:`repro.kernels.ref.predecode`) implies:
+        the first ``<`` it drops, then the depth walk of its events."""
+        from repro.kernels import ref
+
+        rng = np.random.default_rng(5)
+        alphabet = np.frombuffer(b"<<<<//ab_.Z9x >\x00", np.uint8)
+        bufs = [rng.choice(alphabet, size=int(n)).tobytes()
+                for n in rng.integers(0, 24, size=400)]
+        bufs.append(b"<ab>" * 3 + b"</ab>" * 3)
+        data = np.zeros((len(bufs), 32), np.uint8)
+        for i, b in enumerate(bufs):
+            data[i, :len(b)] = np.frombuffer(b, np.uint8)
+        kinds = np.asarray(ref.predecode(data)[0])
+        seen = set()
+        for buf, row, kind in zip(bufs, data, kinds):
+            dropped = np.flatnonzero((row == ord("<")) & (kind == ref.PAD))
+            depth = np.cumsum(np.where(kind == ref.OPEN, 1,
+                                       np.where(kind == ref.CLOSE, -1, 0)))
+            if dropped.size:
+                want = f"undecodable tag marker at byte {dropped[0]}"
+            elif depth.min() < 0:
+                want = "close tag without matching open"
+            elif depth[-1] != 0:
+                want = "unclosed elements"
+            elif depth.max() > 2:
+                want = "exceeds max_depth"
+            else:
+                want = None
+                validate_payload(buf, max_depth=2)
+            seen.add(want and want.split()[0])
+            if want:
+                with pytest.raises(DocumentError, match=want):
+                    validate_payload(buf, max_depth=2)
+        assert seen == {None, "undecodable", "close", "unclosed", "exceeds"}
+
     @given(depth=st.integers(min_value=1, max_value=2 * DEFAULT_MAX_DEPTH))
     @settings(max_examples=20, deadline=None)
     def test_depth_boundary_property(self, depth):

@@ -15,10 +15,13 @@ import pytest
 from repro.core import engines
 from repro.core.dictionary import TagDictionary
 from repro.core.engines.base import FilterEngine
-from repro.core.events import (CLOSE, OPEN, ByteBatch, EventBatch,
-                               EventStream)
+from repro.core.engines.streaming import DEFAULT_BYTE_CHUNK
+from repro.core.events import (CLOSE, OPEN, SEG_SENTINEL, ByteBatch,
+                               EventBatch, EventStream, _sym_table,
+                               decode_tags, encode_bytes, pack_segments)
 from repro.core.nfa import compile_queries
 from repro.data.generator import DTD, gen_corpus, gen_profiles
+from repro.kernels import stream_filter as sf
 
 #: the Pallas interpreter runs on any backend
 MODES = [pytest.param(True, id="interpret")]
@@ -82,6 +85,139 @@ class TestKernelVsScanBatches:
         bb = ByteBatch.from_streams(docs, text_fill=3, bucket=256)
         scan, pallas = engine_pair(nfa, d, interpret)
         assert_same(scan.filter_bytes(bb), pallas.filter_bytes(bb))
+
+
+# ------------------------------------------------ the tag-start bitmap walk
+#: bytes per SMEM chunk of the bytes kernel at the default ``byte_chunk``
+CHUNK = sf._chunk_rows(DEFAULT_BYTE_CHUNK, sf.ROW_BYTES) * sf.ROW_BYTES
+
+
+def _laid_out(ev, at):
+    """Wire bytes of ``ev`` with event ``i``'s ``<`` at byte ``at(i)``,
+    ``x`` filler in between (``at`` must leave room for each tag)."""
+    out = bytearray()
+    for i, (k, t) in enumerate(zip(ev.kind, ev.tag_id)):
+        out += b"x" * (at(i) - len(out))
+        assert len(out) == at(i)
+        sym = TagDictionary.symbols_of(int(t)).encode()
+        out += (b"<" + sym + b">") if k == OPEN else (b"</" + sym + b">")
+    return bytes(out)
+
+
+def _edge_case(case, dtd):
+    """(payloads, pack) of one edge case of the bitmap walk."""
+    docs = gen_corpus(dtd, n_docs=3, nodes_per_doc=90, seed=21)
+    if case == "word-straddle":      # every tag crosses a 32-byte word
+        return [_laid_out(ev, lambda i: 32 * i + 30) for ev in docs], False
+    if case == "chunk-straddle":     # tags at CHUNK-2.. and CHUNK-3..
+        ev = docs[0]
+        assert len(ev.kind) > CHUNK // 32
+        return [_laid_out(ev, lambda i: 32 * i + 30),
+                _laid_out(ev, lambda i: 32 * i + 29)], False
+    if case == "bit-31":             # every word's one bit is its sign
+        return [_laid_out(ev, lambda i: 32 * i + 31) for ev in docs], False
+    if case == "lt-last":            # '<', '</', '<a' as the last bytes
+        body = encode_bytes(docs[0], text_fill=3)
+        return [body + b"<", body + b"</", body + b"<a",
+                b"x" * 31 + b"<"], False
+    if case == "invalid-markers":    # '<' / '</' before non-symbol bytes
+        junk = [b"<!a>", b"</ a>", b"<a!>", b"<<", b"</<", b"< ", b"<a>"]
+        bufs = []
+        for j, ev in enumerate(docs):
+            good = encode_bytes(ev, text_fill=2).split(b">")
+            bufs.append(b">".join(
+                g + junk[(i + j) % len(junk)] for i, g in enumerate(good)))
+        return bufs, False
+    if case == "open-last":          # the last tag opens, in a part word
+        ev, bufs = docs[0], []
+        for j in np.flatnonzero(docs[0].kind == OPEN):
+            body = encode_bytes(EventStream(ev.kind[:j + 1],
+                                            ev.tag_id[:j + 1]))
+            bufs.append(b"x" * ((10 - len(body)) % 32) + body)
+        return bufs, False
+    if case == "tag-dense":          # no text: one tag per 4-5 bytes
+        return [encode_bytes(ev) for ev in docs], False
+    if case == "packed":             # boundaries inside words, empty docs
+        small = gen_corpus(dtd, n_docs=6, nodes_per_doc=7, seed=22)
+        bufs = [encode_bytes(ev, text_fill=1 + i % 3)
+                for i, ev in enumerate(small)]
+        return bufs[:2] + [b""] + bufs[2:] + [b"", b""], True
+    raise ValueError(case)
+
+
+class TestTagStartWalk:
+    """The bytes kernels' scalar walk visits only the tag-start bitmap's
+    set bits; on bytes chosen to stress the bitmap's edges, both launch
+    shapes stay bit-identical to the scan, and the counter of walked tag
+    starts equals the host decoder's event count."""
+
+    @pytest.mark.parametrize("interpret", MODES)
+    @pytest.mark.parametrize("case", [
+        "word-straddle", "chunk-straddle", "bit-31", "lt-last",
+        "open-last", "invalid-markers", "tag-dense", "packed"])
+    def test_bytes_kernels_match_scan(self, interpret, case):
+        dtd, d, qs, nfa = workload(n_queries=24, seed=6)
+        bufs, pack = _edge_case(case, dtd)
+        bb = ByteBatch.from_buffers(bufs)
+        scan, pallas = engine_pair(nfa, d, interpret, segment_target=512)
+        if pack:
+            starts = pack_segments(bb, target_len=512).starts
+            real = starts[(starts > 0) & (starts < SEG_SENTINEL)]
+            assert (real % 32 != 0).any()
+            assert any(len(b) == 0 for b in bufs)
+        oracle = scan.filter_bytes(bb)
+        assert oracle.matched.any()
+        if case == "open-last":      # some document first matches there
+            last = [len(decode_tags(b, _sym_table())[0]) - 1 for b in bufs]
+            assert (oracle.first_event == np.array(last)[:, None]).any()
+        assert_same(oracle, pallas.filter_bytes(bb, pack=pack))
+        sp = pallas.filter_bytes_sparse(bb, pack=pack)
+        assert sp.meta["path"] == "kernel-fused"
+        assert_same(oracle, sp.densify())
+        assert sp.meta["tag_starts"] == sum(
+            len(decode_tags(b, _sym_table())[0]) for b in bufs)
+
+
+def _bitmap_offsets(words: np.ndarray) -> list[np.ndarray]:
+    """(S, W) int32 bitmap words → the set bit offsets of each row."""
+    bits = (words.view(np.uint32)[..., None]
+            >> np.arange(32, dtype=np.uint32)) & 1
+    return [np.flatnonzero(r) for r in bits.reshape(words.shape[0], -1)]
+
+
+class TestTagBitmap:
+    """:func:`repro.kernels.stream_filter.tag_bitmap` sets exactly the
+    offsets where the host decoder starts an event: bit order, rows
+    padded past their bytes, and the lookahead at the row's end."""
+
+    def _check(self, bufs, bucket=None):
+        bb = ByteBatch.from_buffers(bufs, bucket=bucket)
+        words = np.asarray(sf.tag_bitmap(np.asarray(bb.data)))
+        assert words.shape[1] == -(-bb.data.shape[1] // 32)
+        got = _bitmap_offsets(words)
+        for buf, offs in zip(bufs, got):
+            np.testing.assert_array_equal(
+                offs, decode_tags(buf, _sym_table())[0])
+
+    def test_random_rows(self):
+        rng = np.random.default_rng(0)
+        alphabet = np.frombuffer(b"<<<//ab_.Z9x >\x00", np.uint8)
+        self._check([rng.choice(alphabet, size=n).tobytes()
+                     for n in (1, 31, 32, 33, 100, 257, 1000)], bucket=64)
+
+    def test_generated_rows(self):
+        dtd, d, _, _ = workload(n_queries=4, seed=13)
+        docs = gen_corpus(dtd, n_docs=4, nodes_per_doc=60, seed=13)
+        self._check([encode_bytes(ev, text_fill=f)
+                     for f, ev in enumerate(docs)], bucket=128)
+
+    def test_row_end_lookahead(self):
+        """Tags whose symbols are the row's last bytes, with no padding
+        after them: an open tag needs two bytes after ``<``, a close
+        tag three."""
+        self._check([b"x" * 61 + b"<ab", b"x" * 60 + b"</ab",
+                     b"x" * 61 + b"</a", b"x" * 62 + b"<a",
+                     b"x" * 63 + b"<"])
 
 
 # --------------------------------------------------------- depth overflow

@@ -104,6 +104,18 @@ def test_worker_spans_fit_inside_the_stage_time(run):
     assert sum(spent.values()) <= st["seconds"] - st0["seconds"]
 
 
+def test_stage_counts_the_tag_starts_the_kernel_walked(run):
+    """``stats["tag_starts"]``: one per tag the bytes kernel's scalar
+    walk visited, so one per event of every payload the loop served."""
+    from repro.core.events import _sym_table, decode_tags
+
+    want = sum(len(decode_tags(t.payload, _sym_table())[0])
+               for t in run["tickets"])
+    st, st0 = run["stage"].stats, run["stats0"]
+    assert want > 0
+    assert st["tag_starts"] - st0["tag_starts"] == want
+
+
 def test_loop_spans_are_counted(run):
     s = run["loop"].slo_summary()
     # every batch's delivery sleeps SLOW_S in the consumer
