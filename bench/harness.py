@@ -47,6 +47,11 @@ DRAIN_S = 60.0
 #: JAX's compile events; every one of them inside the window is a shape
 #: the warm-up missed (a persistent-cache hit still traces and lowers)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: the kernel that each stage route (``stats["verdict_paths"]``) runs on
+#: the chip, where it is not the configuration's ``kernel``: the 2-D
+#: data-parallel route runs the dense bytes kernel on every chip and
+#: sparsifies its verdict on the host
+ROUTE_KERNELS = {"dense-2d": "stream_filter_bytes_pallas"}
 
 
 class NoChip(RuntimeError):
@@ -91,12 +96,17 @@ class CellSpec:
         cell = cells[name]
         cfg = {c["name"]: c for c in spec["configs"]}[cell["config"]]
         mix = load_json(root / "bench" / "traffic" / f"{cell['traffic']}.json")
+        chips, batch = int(cell["chips"]), int(mix["loop"]["max_batch"])
+        if batch % chips:
+            # every chip of a data-parallel cell takes an equal share
+            raise SystemExit(f"workload {name!r}: a batch of {batch} does "
+                             f"not divide over {chips} chips")
 
         def mine(metrics):
             return [m for m in metrics
                     if name in m.get("workloads", [name])]
 
-        return cls(name, int(cell["chips"]), load_json(root / cfg["file"]),
+        return cls(name, chips, load_json(root / cfg["file"]),
                    mix, mine(spec["end_to_end"]), mine(spec["per_layer"]))
 
 
@@ -188,12 +198,19 @@ class Run:
         self.n_distinct = len(set(self.dep.profiles))
         st = cfg["stage"]
         # every (document, distinct profile) pair of a batch fits the
-        # match buffer: it never overflows into the dense re-run
+        # match buffer: it never overflows into the dense re-run.  A cell
+        # on several chips lays its batch over them on the mesh's "data"
+        # axis, each chip holding the whole plan
+        chips = self.spec.chips
         self.stage = FilterStage(
             self.dep.profiles, d, engine=st["engine"], sparse=st["sparse"],
             keep_unmatched=st["keep_unmatched"], batch_size=self.max_batch,
-            byte_bucket=row,
+            byte_bucket=row, data_shards=chips,
             engine_options={"match_cap": self.max_batch * self.n_distinct})
+        laid = dict(self.stage.mesh.shape) if self.stage.mesh else {}
+        if chips > 1 and laid != {"data": chips, "model": 1}:
+            raise NoChip(f"{chips} data-parallel chips asked for, the "
+                         f"stage's mesh is {laid}")
         meta = getattr(self.stage._eng, "plan_", None)
         meta = meta.meta if meta is not None else {}
         log(f"{len(self.dep.profiles)} profiles ({self.n_distinct} "
@@ -453,7 +470,7 @@ class Tracer:
             self.span = (self.span[0], self.clock())
             jax.profiler.stop_trace()
 
-    def reduce(self, kernel: str, n_chips: int) -> dict | None:
+    def reduce(self, kernels: list[str], n_chips: int) -> dict | None:
         if self.dir is None:
             return None
         from . import trace_reduce
@@ -462,9 +479,14 @@ class Tracer:
             events = trace_reduce.load_xplane(self.dir)
             return trace_reduce.summarize(
                 events, window_s=self.span[1] - self.span[0],
-                kernel=kernel, n_chips=n_chips)
+                kernels=kernels, n_chips=n_chips)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
+
+
+def route_kernels(config: dict, routes: dict) -> list[str]:
+    """The kernels that the routes a run took launched on the chip."""
+    return sorted({ROUTE_KERNELS.get(r, config["kernel"]) for r in routes})
 
 
 def nearest_rank(xs: np.ndarray, q: float) -> float:
@@ -567,7 +589,12 @@ def main(argv=None, *, t_process: float | None = None,
             f"max {lm.max():.3f} ms")
     if args.trace:
         ctx.peaks = peaks
-        ctx.trace = tracer.reduce(spec.config["kernel"], spec.chips)
+        ctx.trace = tracer.reduce(route_kernels(spec.config, run.routes),
+                                  spec.chips)
+        if ctx.trace is not None:
+            log(f"kernel per chip: launches "
+                f"{ctx.trace['kernel_launches_per_chip']}, seconds "
+                f"{ctx.trace['kernel_s_per_chip']}")
     metrics = read_metrics(spec.per_layer if args.trace else spec.end_to_end,
                            ctx, root)
     dev = device_info(devices, ctx)

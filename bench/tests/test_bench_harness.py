@@ -1,5 +1,7 @@
 """The harness on the CPU: result line, window arithmetic, and that the
-comparison refuses the control and the planted faults."""
+comparison refuses the control and the planted faults.  A four-chip cell
+runs in a child process on four virtual CPU devices."""
+import dataclasses
 import json
 import math
 import os
@@ -22,9 +24,54 @@ def root(tmp_path_factory):
     return make_root(tmp_path_factory.mktemp("checkout"))
 
 
-def run_cell(root, capsys, cell, seconds="1.5", seed="4294967311"):
+#: a child on four virtual CPU devices runs one cell through the real
+#: harness, with a fault named by the test module's function (or the
+#: control) planted first: in the stage's fan-out, or where its target
+#: says
+CHILD = """
+import sys
+from pathlib import Path
+
+import jax
+
+from bench import control, harness
+from bench.tests import test_bench_harness as t
+from repro.core.engines.base import FilterEngine
+from repro.data.filter_stage import FilterStage
+
+root, cell, seed, seconds, fault = sys.argv[1:6]
+if fault == "control":
+    control.install(Path(root) / "bench" / "configs" / "linear_xpath.py")
+elif fault:
+    cls, attr = {"_first_chip_only": (FilterEngine, "filter_bytes_sharded2d")
+                 }.get(fault, (FilterStage, "_fan_out"))
+    setattr(cls, attr, getattr(t, fault)(getattr(cls, attr)))
+sys.exit(harness.main(["--workload", cell, "--seed", seed, "--seconds",
+                       seconds, "--trace", "0"], root=Path(root),
+                      check=lambda chips: jax.devices()))
+"""
+
+
+def run_child(root, cell, seconds, seed, fault=""):
+    """The last line of a four-device child's output, and its errors."""
+    path = os.pathsep.join([str(ROOT), str(ROOT / "src"),
+                            os.environ.get("PYTHONPATH", "")])
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": path,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    proc = subprocess.run(
+        [sys.executable, "-c", CHILD, str(root), cell, seed, seconds,
+         fault], env=env, capture_output=True, text=True, timeout=300,
+        cwd=ROOT)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stderr
+
+
+def run_cell(root, capsys, cell, seconds="1.5", seed="4294967311",
+             fault=""):
     import jax
 
+    if harness.CellSpec.load(cell, root).chips > 1:
+        return run_child(root, cell, seconds, seed, fault)[0]
     rc = harness.main(["--workload", cell, "--seed", seed, "--seconds",
                        seconds, "--trace", "0"], root=root,
                       check=lambda chips: jax.devices())
@@ -35,7 +82,8 @@ def run_cell(root, capsys, cell, seconds="1.5", seed="4294967311"):
 
 @pytest.mark.parametrize("cell,metrics", [
     ("tiny.backlog", {"mb_per_s", "setup_s"}),
-    ("tiny.poisson", {"p50_ms", "p95_ms", "setup_s"})])
+    ("tiny.poisson", {"p50_ms", "p95_ms", "setup_s"}),
+    ("tiny.dp4", {"mb_per_s", "setup_s"})])
 def test_result_line_has_the_contracts_keys(root, capsys, cell, metrics):
     res = run_cell(root, capsys, cell)
     assert CONTRACT_KEYS <= set(res) <= CONTRACT_KEYS | {"checks"}
@@ -66,7 +114,18 @@ def _alter_first(orig):
     return fan_out
 
 
-@pytest.mark.parametrize("cell", ["tiny.backlog", "tiny.poisson"])
+def _first_chip_only(orig):
+    """The verdicts of the chips past the first left out of the gather."""
+    def filter_bytes_sharded2d(self, bb, *a, **k):
+        res = orig(self, bb, *a, **k)
+        matched = np.array(res.matched)
+        matched[-(-bb.batch_size // 4):] = False
+        return dataclasses.replace(res, matched=matched)
+    return filter_bytes_sharded2d
+
+
+@pytest.mark.parametrize("cell", ["tiny.backlog", "tiny.poisson",
+                                  "tiny.dp4"])
 @pytest.mark.parametrize("fault,check", [(_drop_half, "missing"),
                                          (_alter_first, "wrong_lists")])
 def test_planted_faults_are_not_correct(root, capsys, monkeypatch, cell,
@@ -75,22 +134,70 @@ def test_planted_faults_are_not_correct(root, capsys, monkeypatch, cell,
 
     monkeypatch.setattr(FilterStage, "_fan_out",
                         fault(FilterStage._fan_out))
-    res = run_cell(root, capsys, cell)
+    res = run_cell(root, capsys, cell, fault=fault.__name__)
     assert res["correct"] is False
     assert res["checks"][check]["value"] > res["checks"][check]["limit"]
 
 
-@pytest.mark.parametrize("cell", ["tiny.backlog", "tiny.poisson"])
+@pytest.mark.parametrize("cell", ["tiny.backlog", "tiny.poisson",
+                                  "tiny.dp4"])
 def test_control_is_not_correct(root, capsys, cell):
     from bench import control
 
     restore = control.install(root / "bench" / "configs" / "linear_xpath.py")
     try:
-        res = run_cell(root, capsys, cell)
+        res = run_cell(root, capsys, cell, fault="control")
     finally:
         restore()
     assert res["correct"] is False
     assert res["checks"]["wrong_lists"]["value"] > 0
+
+
+def test_four_chip_cell_lays_every_batch_over_the_chips(root):
+    """Each batch of ``tiny.dp4`` is split over four replicas of the
+    plan on the mesh's ``data`` axis: the stage reports the 2-D route
+    for every batch it filtered, and the lists delivered are right."""
+    res, err = run_child(root, "tiny.dp4", "1.5", "2147483659")
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["device"]["count"] == 4
+    routes = [line for line in err.splitlines() if "routes" in line]
+    assert len(routes) == 1
+    assert routes[0].rsplit("routes ", 1)[1].startswith("{'dense-2d': ")
+    assert "'kernel-fused'" not in routes[0]
+
+
+def test_chips_left_out_of_the_gather_are_not_correct(root):
+    res, err = run_child(root, "tiny.dp4", "1.5", "4294967311",
+                         "_first_chip_only")
+    assert res["correct"] is False
+    assert res["checks"]["wrong_lists"]["value"] > 0
+
+
+def test_one_chip_cell_builds_the_unsharded_stage(root):
+    run = harness.Run(harness.CellSpec.load("tiny.backlog", root), 7, 1.0,
+                      0.0)
+    run.build()
+    assert run.stage.data_shards == 1
+    assert run.stage.mesh is None and run.stage.sharded_ is None
+
+
+def test_batch_that_does_not_divide_over_the_chips_is_refused(tmp_path):
+    root = make_root(tmp_path)
+    mix = root / "bench" / "traffic" / "tiny-dp4.json"
+    body = json.loads(mix.read_text())
+    body["loop"]["max_batch"] = 6
+    mix.write_text(json.dumps(body))
+    with pytest.raises(SystemExit, match="does not divide over 4 chips"):
+        harness.CellSpec.load("tiny.dp4", root)
+    assert harness.CellSpec.load("tiny.backlog", root).chips == 1
+
+
+def test_route_kernels():
+    cfg = {"kernel": "stream_filter_bytes_pallas_sparse"}
+    assert harness.route_kernels(cfg, {"kernel-fused": 9}) == [
+        "stream_filter_bytes_pallas_sparse"]
+    assert harness.route_kernels(cfg, {"dense-2d": 9}) == [
+        "stream_filter_bytes_pallas"]
 
 
 class _Ticket:

@@ -76,3 +76,11 @@ def test_readers_are_found_by_quantity():
         path = harness.reader_path(name, ROOT)
         assert path.name == name.rsplit(".", 1)[0] + ".py"
         assert path.exists()
+
+
+def test_every_metric_in_the_benchmark_has_a_reader():
+    import json
+
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        assert hasattr(reader(m["name"]), "read"), m["name"]

@@ -26,12 +26,19 @@ MIXES = {
                 "loop": {"max_batch": 2, "deadline_ms": 20,
                          "max_inflight": 2, "queue_cap": 16,
                          "overload": "shed"}},
+    "dp4": {"arrivals": {"kind": "backlog"}, "warm_batches": 2,
+            "loop": {"max_batch": 8, "deadline_ms": 600000,
+                     "max_inflight": 2, "queue_cap": 32,
+                     "overload": "block"}},
 }
+#: chips per mix; the others take one
+CHIPS = {"dp4": 4}
 
 
 def make_root(tmp: Path) -> Path:
-    """A checkout holding ``BENCHMARK.json`` with cells ``tiny.backlog``
-    and ``tiny.poisson``, the real metric readers and reference."""
+    """A checkout holding ``BENCHMARK.json`` with cells ``tiny.backlog``,
+    ``tiny.poisson`` and ``tiny.dp4`` (four chips), the real metric
+    readers and reference."""
     spec = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     b = tmp / "bench"
     shutil.copytree(BENCH / "metrics", b / "metrics")
@@ -44,7 +51,8 @@ def make_root(tmp: Path) -> Path:
     for mix, body in MIXES.items():
         (b / "traffic" / f"tiny-{mix}.json").write_text(json.dumps(body))
         cells.append({"name": f"tiny.{mix}", "config": "tiny",
-                      "traffic": f"tiny-{mix}", "chips": 1, "why": "test"})
+                      "traffic": f"tiny-{mix}", "chips": CHIPS.get(mix, 1),
+                      "why": "test"})
     names = [c["name"] for c in cells]
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:

@@ -10,10 +10,11 @@ today).  Host threads are lines of ``/host:CPU``.
 * busy: the union of the ``XLA Ops`` intervals of each chip, averaged
   over the chips of the cell;
 * kernel time: the summed durations of the ops whose name starts with
-  ``%<kernel>`` (or ``<kernel>``), and how many ran;
-* breakdown: the ten ops with the most device time, and the ten longest
-  gaps between busy intervals of chip 0, each named by what the host
-  was doing in it (see :func:`idle_gaps`).
+  ``%<kernel>`` (or ``<kernel>``) for any of the run's kernels, and how
+  many ran, averaged over the chips and per chip;
+* breakdown: the ten ops with the most device time per chip, and the
+  ten longest gaps between busy intervals of chip 0, each named by what
+  the host was doing in it (see :func:`idle_gaps`).
 """
 from __future__ import annotations
 
@@ -118,28 +119,36 @@ def idle_gaps(busy: list[tuple[float, float]], host: list[Event],
     return out
 
 
-def summarize(events: list[Event], *, window_s: float, kernel: str,
+def summarize(events: list[Event], *, window_s: float, kernels: list[str],
               n_chips: int) -> dict:
-    """Busy seconds, kernel seconds and launches, and the breakdown."""
+    """Busy seconds, kernel seconds and launches (mean over the cell's
+    ``n_chips`` chips, and per chip ``0 .. n_chips - 1``), and the
+    breakdown."""
     chips = device_ops(events)
     busy_per_chip = {c: union([(e.start_ns, e.end_ns) for e in evs])
                      for c, evs in chips.items()}
     busy_s = sum(sum(e - s for s, e in iv)
                  for iv in busy_per_chip.values()) / 1e9 / n_chips
-    pat = re.compile(rf"^%?{re.escape(kernel)}(\.\d+)?( |$)")
-    kern = [e for evs in chips.values() for e in evs if pat.match(e.name)]
+    alts = "|".join(re.escape(k) for k in kernels)
+    pat = re.compile(rf"^%?(?:{alts})(\.\d+)?( |$)")
+    kern = {c: [e for e in chips.get(c, []) if pat.match(e.name)]
+            for c in range(n_chips)}
+    kernel_s = [sum(e.dur_ns for e in kern[c]) / 1e9 for c in range(n_chips)]
+    launches = [len(kern[c]) for c in range(n_chips)]
     by_name: dict[str, float] = {}
     for evs in chips.values():
         for e in evs:
             n = short_name(e.name)
-            by_name[n] = by_name.get(n, 0.0) + e.dur_ns / 1e9
+            by_name[n] = by_name.get(n, 0.0) + e.dur_ns / 1e9 / n_chips
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
     host = [e for e in events if e.plane == HOST_PLANE and e.dur_ns > 0]
     return {
         "busy_s": busy_s,
         "window_s": window_s,
-        "kernel_s": sum(e.dur_ns for e in kern) / 1e9 / n_chips,
-        "kernel_launches": len(kern) / n_chips,
+        "kernel_s": sum(kernel_s) / n_chips,
+        "kernel_launches": sum(launches) / n_chips,
+        "kernel_s_per_chip": kernel_s,
+        "kernel_launches_per_chip": launches,
         "breakdown": {
             "device_ops": [[n, s] for n, s in top],
             "idle_gaps": idle_gaps(busy_per_chip.get(0, []), host),
