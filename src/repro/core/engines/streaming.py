@@ -335,7 +335,7 @@ def _device_rows(buf, cnt, cap: int, ndev: int = 1
     leading axis; only the first ``min(count_d, cap)`` rows of each are
     real.  Returns ``((docs, cls, first), total_count, overflowed)``
     where overflow means ANY device saturated its buffer.  A count row
-    may carry more columns (:func:`_tag_starts`); the first is the
+    may carry more columns (:func:`_walk_counts`); the first is the
     match count.
     """
     buf = np.asarray(buf).reshape(ndev, -1, 3)
@@ -346,11 +346,13 @@ def _device_rows(buf, cnt, cap: int, ndev: int = 1
             int(cnt.sum()), bool((cnt > int(cap)).any()))
 
 
-def _tag_starts(cnt) -> int:
-    """Tag starts the bytes kernels walked, summed over devices: the
-    second column of the count rows :func:`_device_rows` has read back
-    (a host copy already made, so no further sync)."""
-    return int(np.asarray(cnt).reshape(-1, 2)[:, 1].sum())
+def _walk_counts(cnt) -> dict:
+    """What the bytes kernels' walks counted, summed over devices: the
+    tag starts (second column of the count rows :func:`_device_rows`
+    has read back) and the chunks fetched from HBM (third column); a
+    host copy already made, so no further sync."""
+    cols = np.asarray(cnt).reshape(-1, 3).sum(axis=0)
+    return {"tag_starts": int(cols[1]), "stream_chunks": int(cols[2])}
 
 
 def _lane_classes(plan: base.FilterPlan) -> tuple[np.ndarray, np.ndarray]:
@@ -1263,7 +1265,7 @@ class StreamingEngine(base.FilterEngine):
                 bufs, n, cap, offsets, members, batch_size=b,
                 n_queries=self.n_queries, live_ids=None,
                 meta={"path": "kernel-fused", "launch": "bytes",
-                      "tag_starts": _tag_starts(cnt)},
+                      **_walk_counts(cnt)},
                 overflowed=over,
                 dense_fallback=lambda: self.filter_bytes(bb, pack=pack))
         sp.meta.update(spans)
@@ -1320,7 +1322,7 @@ class StreamingEngine(base.FilterEngine):
             bufs, n, cap, offsets, members, batch_size=b,
             n_queries=len(live_ids), live_ids=live_ids,
             meta={"path": "kernel-fused", "launch": "bytes",
-                  "tag_starts": _tag_starts(cnt)},
+                  **_walk_counts(cnt)},
             overflowed=over,
             dense_fallback=lambda: self.filter_bytes_sharded(
                 bb, sharded, mesh=mesh))

@@ -209,8 +209,9 @@ class FilterStage:
                       "seconds": 0.0, "pair_matches": 0, "pairs": 0,
                       "put_seconds": 0.0, "overlapped_batches": 0,
                       "verdict_bytes": 0, "rebalances": 0,
-                      # tag starts the bytes kernel walked (``meta``)
-                      "tag_starts": 0,
+                      # tag starts the bytes kernel walked and the
+                      # chunks it fetched from HBM (``meta``)
+                      "tag_starts": 0, "stream_chunks": 0,
                       **dict.fromkeys(SPAN_KEYS, 0.0),
                       # sparse batches per engine route (``meta["path"]``)
                       "verdict_paths": {}}
@@ -454,6 +455,7 @@ class FilterStage:
             path = res.meta.get("path")
             paths[path] = paths.get(path, 0) + 1
             self.stats["tag_starts"] += res.meta.get("tag_starts", 0)
+            self.stats["stream_chunks"] += res.meta.get("stream_chunks", 0)
             for k in SPAN_KEYS:
                 self.stats[k] += res.meta.get(k, 0.0)
         else:

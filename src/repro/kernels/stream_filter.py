@@ -26,15 +26,17 @@ Layout (see the README "Kernel hot path" diagram):
   sublane sum that packs the gathered bits back into words, and three
   bitwise ops — no per-event unpack of the stack, no matmul.
 * **events stream through SMEM chunks** — each document's fused
-  ``(kind<<16)|tag`` event words (or raw wire bytes, for the one-launch
-  bytes kernel) arrive as a lane-dense VMEM block; the kernel copies one
-  chunk of ``(8, 128)`` tiles at a time into SMEM and walks it with the
-  scalar core (the "8-bit streaming interface" of Fig 3), stopping at
-  the document's last real event or byte.  The bytes kernel's scalar
-  core stops only where a tag starts: every byte position is classified
-  beforehand on the vector side into a tag-start bitmap
-  (:func:`tag_bitmap`, one bit per byte), and the walk visits the
-  bitmap's words and, in each, its set bits.
+  ``(kind<<16)|tag`` event words arrive as a lane-dense VMEM block; the
+  kernel copies one chunk of ``(8, 128)`` tiles at a time into SMEM and
+  walks it with the scalar core (the "8-bit streaming interface" of
+  Fig 3), stopping at the document's last real event or byte.  The
+  one-launch bytes kernel leaves its raw wire bytes in HBM and copies
+  each chunk HBM→SMEM straight, the next chunk's copy in flight while
+  the current one is walked, so a row of any length streams through a
+  fixed few KB of fast memory.  Its scalar core stops only where a tag
+  starts: every byte position is classified beforehand on the vector
+  side into a tag-start bitmap (:func:`tag_bitmap`, one bit per byte),
+  and the walk visits the bitmap's words and, in each, its set bits.
 
 Per-block tables are stored by :mod:`repro.kernels.blocks` in their
 canonical packed form (``(G, WB)`` words, ``(G, WB, 32)`` parent
@@ -310,7 +312,8 @@ def _sparse_init(out_ref, cnt_ref):
         out_ref[0] = jnp.full(out_ref.shape[1:], -1, jnp.int32)
         out_ref[1] = jnp.full(out_ref.shape[1:], -1, jnp.int32)
         out_ref[2] = jnp.full(out_ref.shape[1:], NO_MATCH, jnp.int32)
-        cnt_ref[0, 0] = 0
+        for j in range(cnt_ref.shape[1]):
+            cnt_ref[0, j] = 0
 
 
 def _emit_rows(matched, first, cls, doc, out_ref, cnt_ref, stage_ref,
@@ -571,22 +574,27 @@ def stream_filter_pallas_sparse(events: jax.Array, doc_ids: jax.Array,
 
 
 # ----------------------------------------------- one-launch bytes kernel
-def _bytes_stream(data_ref, bm_ref, n_bytes, starts_ref, st0, stack_ref,
-                  mbuf_ref, fbuf_ref, bbuf_ref, bmbuf_ref, sem_ref, tb,
-                  init_row, *, max_depth: int, n_tags: int, qr: int):
+def _bytes_stream(data_ref, bm_ref, seg, n_bytes, starts_ref, st0,
+                  stack_ref, mbuf_ref, fbuf_ref, bbuf_ref, bmbuf_ref,
+                  sem_ref, tb, init_row, *, max_depth: int, n_tags: int,
+                  qr: int):
     """Streaming body of the one-launch bytes kernel, one grid cell.
 
     Shared verbatim by the dense (:func:`_bytes_kernel`) and
-    fused-sparse (:func:`_bytes_kernel_sparse`) launch shapes.  Per chunk
-    of bytes: copy the int32-packed bytes VMEM→SMEM (the chunk plus one
-    lookahead tile, so tags straddling the boundary decode whole) and
-    the chunk's words of the tag-start bitmap (:func:`tag_bitmap`, one
-    bit per byte, made on the vector side before the launch).  The
-    scalar core walks the bitmap words, not the bytes: an empty word is
-    one load and one compare; a non-empty one gives up its set bits
-    lowest first (``w & -w`` isolates one, ``w & (w - 1)`` clears it;
-    bit 31 makes the word negative, which neither minds).  Each set bit
-    is a tag start: the §3.4 character pre-decode
+    fused-sparse (:func:`_bytes_kernel_sparse`) launch shapes.  Segment
+    ``seg``'s int32-packed bytes and its tag-start bitmap
+    (:func:`tag_bitmap`, one bit per byte, made on the vector side
+    before the launch) stay in HBM; the walk copies them HBM→SMEM one
+    chunk at a time (the chunk's bytes plus one lookahead tile, so tags
+    straddling the boundary decode whole, and the chunk's bitmap words)
+    into one of two slots, and starts the copy of chunk ``ci + 1`` into
+    the other slot before it walks chunk ``ci``.  So no buffer grows
+    with the row, and the copies hide behind the walk.  The scalar core
+    walks the bitmap words, not the bytes: an empty word is one load and
+    one compare; a non-empty one gives up its set bits lowest first
+    (``w & -w`` isolates one, ``w & (w - 1)`` clears it; bit 31 makes
+    the word negative, which neither minds).  Each set bit is a tag
+    start: the §3.4 character pre-decode
     (:func:`repro.kernels.parse.fused_predecode`, the same function the
     vector parser uses) runs on its byte and three lookahead bytes, and
     the tag becomes one event through the shared :func:`_advance`
@@ -599,7 +607,8 @@ def _bytes_stream(data_ref, bm_ref, n_bytes, starts_ref, st0, stack_ref,
     lanes to the (D, QR, 128) result buffers and re-roots the stack —
     this is how short documents share a grid slot instead of padding to
     the longest.  On return every document row of ``mbuf_ref`` /
-    ``fbuf_ref`` is final.
+    ``fbuf_ref`` is final, every copy has landed, and the result is the
+    number of chunks the walk fetched.
     """
     # result buffers for every document in this segment; empty doc slots
     # keep these initial values (flushed by the boundary loop unchanged)
@@ -607,23 +616,44 @@ def _bytes_stream(data_ref, bm_ref, n_bytes, starts_ref, st0, stack_ref,
     fbuf_ref[...] = jnp.full_like(fbuf_ref, NO_MATCH)
     _reset_stack(stack_ref, init_row)
 
-    rows = bbuf_ref.shape[0] - TILE_ROWS
+    rows = bbuf_ref.shape[1] - TILE_ROWS
     chunk = rows * ROW_BYTES
-    bm_rows = bmbuf_ref.shape[0]
+    bm_rows = bmbuf_ref.shape[1]
+    n_chunks = (n_bytes + chunk - 1) // chunk
 
-    def byte_at(q):
-        # byte q of the staged int32 words, little-endian
-        w = bbuf_ref[q >> 9, (q >> 2) & (LANES - 1)]
-        return jax.lax.shift_right_logical(w, (q & 3) * 8) & 0xFF
+    def fetch(ci, slot):
+        # chunk ci's bytes (+ lookahead tile) and bitmap words → slot
+        return (
+            pltpu.make_async_copy(
+                data_ref.at[seg, pl.ds(pl.multiple_of(ci * rows, TILE_ROWS),
+                                       rows + TILE_ROWS), :],
+                bbuf_ref.at[slot], sem_ref.at[slot]),
+            pltpu.make_async_copy(
+                bm_ref.at[seg, pl.ds(pl.multiple_of(ci * bm_rows, TILE_ROWS),
+                                     bm_rows), :],
+                bmbuf_ref.at[slot], sem_ref.at[2 + slot]))
+
+    @pl.when(n_chunks > 0)
+    def _():
+        for cp in fetch(0, 0):
+            cp.start()
 
     def chunk_body(ci, carry):
-        _to_smem(data_ref.at[0, pl.ds(pl.multiple_of(ci * rows, TILE_ROWS),
-                                      rows + TILE_ROWS), :],
-                 bbuf_ref, sem_ref)
-        _to_smem(bm_ref.at[0, pl.ds(pl.multiple_of(ci * bm_rows, TILE_ROWS),
-                                    bm_rows), :],
-                 bmbuf_ref, sem_ref)
+        slot = ci & 1
+
+        @pl.when(ci + 1 < n_chunks)
+        def _():
+            for cp in fetch(ci + 1, 1 - slot):
+                cp.start()
+
+        for cp in fetch(ci, slot):
+            cp.wait()
         base = ci * chunk
+
+        def byte_at(q):
+            # byte q of the staged int32 words, little-endian
+            w = bbuf_ref[slot, q >> 9, (q >> 2) & (LANES - 1)]
+            return jax.lax.shift_right_logical(w, (q & 3) * 8) & 0xFF
 
         def on_event(p, fused, carry):
             d, nxt, depth, doc0, ord_, matched, first = carry
@@ -660,20 +690,21 @@ def _bytes_stream(data_ref, bm_ref, n_bytes, starts_ref, st0, stack_ref,
 
             return jax.lax.while_loop(
                 lambda c: c[0] != 0, bit_body,
-                (bmbuf_ref[k >> 7, k & (LANES - 1)],) + carry)[1:]
+                (bmbuf_ref[slot, k >> 7, k & (LANES - 1)],) + carry)[1:]
 
         return jax.lax.fori_loop(
             0, jnp.minimum(chunk, n_bytes - base + 31) >> 5, word_body,
             carry)
 
     d, _, _, _, _, matched, first = jax.lax.fori_loop(
-        0, (n_bytes + chunk - 1) // chunk, chunk_body,
+        0, n_chunks, chunk_body,
         (jnp.int32(0), starts_ref[st0 + 1], jnp.int32(0), jnp.int32(0),
          jnp.int32(0)) + _lane_init(qr))
     # flush the document the stream ended inside; remaining (empty) doc
     # slots keep their initial rows
     mbuf_ref[d] = matched
     fbuf_ref[d] = first
+    return n_chunks
 
 
 def _bytes_kernel(n_bytes_ref, starts_ref, data_ref, bm_ref, tagmask_ref,
@@ -687,7 +718,7 @@ def _bytes_kernel(n_bytes_ref, starts_ref, data_ref, bm_ref, tagmask_ref,
     qr = accw_ref.shape[0]
     tb = _block_tables(tagmask_ref, pw_ref, pb_ref, self_ref, accw_ref,
                        accb_ref)
-    _bytes_stream(data_ref, bm_ref, n_bytes_ref[s], starts_ref,
+    _bytes_stream(data_ref, bm_ref, s, n_bytes_ref[s], starts_ref,
                   s * (n_docs + 1), stack_ref, mbuf_ref, fbuf_ref, bbuf_ref,
                   bmbuf_ref, sem_ref, tb, init_ref[...],
                   max_depth=max_depth, n_tags=n_tags, qr=qr)
@@ -699,22 +730,23 @@ def _bytes_kernel_sparse(n_bytes_ref, starts_ref, docmap_ref, data_ref,
                          bm_ref, tagmask_ref, pw_ref, pb_ref, self_ref,
                          init_ref, accw_ref, accb_ref, lane_ref, out_ref,
                          cnt_ref, stack_ref, mbuf_ref, fbuf_ref, bbuf_ref,
-                         bmbuf_ref, stage_ref, stage_sm, sem_ref, *,
+                         bmbuf_ref, sem_ref, stage_ref, stage_sm, *,
                          max_depth: int, n_tags: int, n_docs: int,
                          doc_axis: int, cap: int):
     """Sparse twin of :func:`_bytes_kernel`: after the stream, every
     document row of the segment is appended to the shared bounded match
     buffer (``docmap`` names each slot's global batch row; ``-1`` pad
-    slots emit nothing)."""
+    slots emit nothing).  The counter's second column sums the chunks
+    every program's walk fetched."""
     s = pl.program_id(doc_axis)
     qr = accw_ref.shape[0]
     _sparse_init(out_ref, cnt_ref)
     tb = _block_tables(tagmask_ref, pw_ref, pb_ref, self_ref, accw_ref,
                        accb_ref)
-    _bytes_stream(data_ref, bm_ref, n_bytes_ref[s], starts_ref,
-                  s * (n_docs + 1), stack_ref, mbuf_ref, fbuf_ref, bbuf_ref,
-                  bmbuf_ref, sem_ref, tb, init_ref[...],
-                  max_depth=max_depth, n_tags=n_tags, qr=qr)
+    cnt_ref[0, 1] += _bytes_stream(
+        data_ref, bm_ref, s, n_bytes_ref[s], starts_ref, s * (n_docs + 1),
+        stack_ref, mbuf_ref, fbuf_ref, bbuf_ref, bmbuf_ref, sem_ref, tb,
+        init_ref[...], max_depth=max_depth, n_tags=n_tags, qr=qr)
     cls = lane_ref[...]
 
     def doc_body(dd, carry):
@@ -768,39 +800,46 @@ def _byte_rows(data: jax.Array, rows: int
     nseg, length = data.shape
     chunk = rows * ROW_BYTES
     n_chunks = -(-length // chunk)
-    npad = n_chunks * chunk + TILE_ROWS * ROW_BYTES
-    data = jnp.pad(data, ((0, 0), (0, npad - length)))
+    npad = n_chunks * chunk
+    if npad != length:
+        data = jnp.pad(data, ((0, 0), (0, npad - length)))
     pos = jax.lax.broadcasted_iota(jnp.int32, data.shape, 1)
     ends = jnp.max(jnp.where(data != 0, pos + 1, 0), axis=1)
+    # the lookahead tile is padded on as int32 words: padding the uint8
+    # rows before the bitcast costs the TPU compiler a minute at one
+    # 10 MB row
     words = jax.lax.bitcast_convert_type(
         data.reshape(nseg, npad // 4, 4), jnp.int32)
+    words = jnp.pad(words, ((0, 0), (0, TILE_ROWS * LANES)))
     with jax.named_scope("tag_bitmap"):
-        bm = tag_bitmap(data)[:, :n_chunks * chunk // 32]
+        bm = tag_bitmap(data)
         tag_starts = jnp.sum(jax.lax.population_count(bm))
         bm = bm.reshape(nseg, n_chunks, -1, LANES)
         bm = jnp.pad(bm, ((0, 0), (0, 0),
                           (0, _bitmap_rows(rows) - bm.shape[2]), (0, 0)))
-    return (words.reshape(nseg, npad // (4 * LANES), LANES),
+    return (words.reshape(nseg, -1, LANES),
             bm.reshape(nseg, -1, LANES), ends.astype(jnp.int32), tag_starts)
 
 
-def _byte_specs(by_doc, words: jax.Array, bm: jax.Array) -> list:
-    """BlockSpecs of one segment's byte words and tag-start bitmap."""
-    return [pl.BlockSpec((1,) + x.shape[1:],
-                         lambda *ids: by_doc(*ids) + (0, 0))
-            for x in (words, bm)]
+def _byte_specs() -> list:
+    """Specs of the byte words and the tag-start bitmap: both stay in
+    HBM, and :func:`_bytes_stream` copies them chunk by chunk."""
+    return [pl.BlockSpec(memory_space=pl.ANY)] * 2
 
 
 def _bytes_scratch(max_depth: int, n_docs: int, qr: int,
                    rows: int) -> list:
+    """The bytes kernels' scratch: none of it grows with the row."""
     return [
         pltpu.VMEM((max_depth + 2, LANES), jnp.int32),   # tag stack
         pltpu.VMEM((n_docs, qr, LANES), jnp.int32),      # matched buf
         pltpu.VMEM((n_docs, qr, LANES), jnp.int32),      # first buf
-        # one byte chunk + its lookahead tile, as int32 words
-        pltpu.SMEM((rows + TILE_ROWS, LANES), jnp.int32),
-        # the chunk's tag-start bitmap words
-        pltpu.SMEM((_bitmap_rows(rows), LANES), jnp.int32),
+        # two slots of one byte chunk + its lookahead tile, int32 words
+        pltpu.SMEM((2, rows + TILE_ROWS, LANES), jnp.int32),
+        # two slots of the chunk's tag-start bitmap words
+        pltpu.SMEM((2, _bitmap_rows(rows), LANES), jnp.int32),
+        # one DMA semaphore per slot: bytes, then bitmap
+        pltpu.SemaphoreType.DMA((4,)),
     ]
 
 
@@ -848,14 +887,12 @@ def stream_filter_bytes_pallas(data: jax.Array, starts: jax.Array,
                           n_tags=dims["n_tags"], n_docs=n_docs,
                           doc_axis=doc_axis),
         grid=grid,
-        in_specs=[_smem_full(), _smem_full(),
-                  *_byte_specs(by_doc, words, bm)]
+        in_specs=[_smem_full(), _smem_full(), *_byte_specs()]
         + _table_specs(by_block, dims),
         out_specs=[out_spec, out_spec],
         out_shape=[jax.ShapeDtypeStruct((nseg, g, n_docs, qr, LANES),
                                         jnp.int32)] * 2,
-        scratch_shapes=_bytes_scratch(max_depth, n_docs, qr, rows)
-        + [pltpu.SemaphoreType.DMA((1,))],
+        scratch_shapes=_bytes_scratch(max_depth, n_docs, qr, rows),
         interpret=interpret,
         name="stream_filter_bytes_pallas",
     )(ends, starts.reshape(-1).astype(jnp.int32), words, bm, *tabs)
@@ -889,9 +926,11 @@ def stream_filter_bytes_pallas_sparse(data: jax.Array, starts: jax.Array,
     (``SegmentPack.doc_ids``; ``-1`` = unused slot, dropped);
     ``lane_cls`` (G, QB) int32 accept-class names.  Returns
     ``(buf, count)`` with the same validity/overflow contract as the
-    event-stream sparse wrapper, except that ``count`` is ``(1, 2)``:
-    the match count, then the tag starts the scalar walk visited per
-    state-word block (the set bits of :func:`tag_bitmap`).
+    event-stream sparse wrapper, except that ``count`` is ``(1, 3)``:
+    the match count, the tag starts the scalar walk visited per
+    state-word block (the set bits of :func:`tag_bitmap`), and the
+    chunks the walks fetched from HBM, summed over every (segment,
+    state-word block) program.
     """
     from . import interpret_default
 
@@ -905,32 +944,32 @@ def stream_filter_bytes_pallas_sparse(data: jax.Array, starts: jax.Array,
     words, bm, ends, tag_starts = _byte_rows(data, rows)
     g, qr = dims["g"], dims["qr"]
     brows = _buffer_rows(cap)
-    grid, doc_axis, by_block, by_doc = _grid_maps(grid_order, nseg, g)
+    grid, doc_axis, by_block, _ = _grid_maps(grid_order, nseg, g)
     out, cnt = pl.pallas_call(
         functools.partial(_bytes_kernel_sparse, max_depth=max_depth,
                           n_tags=dims["n_tags"], n_docs=n_docs,
                           doc_axis=doc_axis, cap=int(cap)),
         grid=grid,
         in_specs=[_smem_full(), _smem_full(), _smem_full(),
-                  *_byte_specs(by_doc, words, bm)]
+                  *_byte_specs()]
         + _table_specs(by_block, dims, with_cls=True),
         out_specs=[
             pl.BlockSpec((3, brows, LANES), lambda *ids: (0, 0, 0)),
-            pl.BlockSpec((1, 1), lambda *ids: (0, 0),
+            pl.BlockSpec((1, 2), lambda *ids: (0, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((3, brows, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((1, 2), jnp.int32),
         ],
         scratch_shapes=_bytes_scratch(max_depth, n_docs, qr, rows) + [
             pltpu.VMEM((2 * qr, LANES), jnp.int32),
             pltpu.SMEM((2 * qr, LANES), jnp.int32),
-            pltpu.SemaphoreType.DMA((1,)),
         ],
         interpret=interpret,
         name="stream_filter_bytes_pallas_sparse",
     )(ends, starts.reshape(-1).astype(jnp.int32),
       doc_map.reshape(-1).astype(jnp.int32), words, bm, *tabs)
     return (_match_list(out, int(cap)),
-            jnp.concatenate([cnt, tag_starts.reshape(1, 1)], axis=1))
+            jnp.stack([cnt[0, 0], tag_starts.astype(jnp.int32),
+                       cnt[0, 1]]).reshape(1, 3))

@@ -6,7 +6,8 @@ The streaming megakernels and the pre-decode kernel are compiled for a
 blocks), batches of 64 documents in 68 KB rows, and a match buffer
 that holds every (document, accept state) pair.  The bytes kernels
 compile besides at the benchmark's ``xmark-1k`` widths: 81,920-byte
-rows in batches of 16 and 64.  Mosaic refuses here
+rows in batches of 16 and 64, and the sparse one at ``xmark-10m``'s
+10.5 MiB rows in batches of 1 and 4.  Mosaic refuses here
 what it would refuse on the chip — block shapes off the tiling, gathers
 it cannot lower, more fast memory than a kernel may use — at no chip
 time.  Nothing runs, so this says nothing about results or speed.
@@ -36,6 +37,9 @@ EVENTS = 8192
 #: the ``xmark-1k`` cell's row (``byte_bucket``) and batch sizes
 XMARK_ROW_BYTES = 81_920
 XMARK_BATCHES = (16, 64)
+#: the ``xmark-10m`` cell's row: 10.5 MiB, 2,688 chunks of 4,096 bytes
+XMARK10M_ROW_BYTES = 11_010_048
+XMARK10M_BATCHES = (1, 4)
 
 
 @pytest.fixture(scope="module")
@@ -130,6 +134,17 @@ def test_bytes_kernels_compile_at_xmark_widths(name, batch, plan, one_chip):
     plan_, per_doc = plan
     compiled = _program(name, plan_, per_doc, one_chip, batch=batch,
                         row_bytes=XMARK_ROW_BYTES).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch", XMARK10M_BATCHES)
+def test_bytes_kernel_streams_whole_10mb_rows(batch, plan, one_chip):
+    """A 10.5 MiB row: the bytes stay in HBM and reach the scalar core
+    one chunk at a time, so no buffer of fast memory grows with the row
+    and a batch of several such rows compiles."""
+    plan_, per_doc = plan
+    compiled = _program("bytes-sparse", plan_, per_doc, one_chip,
+                        batch=batch, row_bytes=XMARK10M_ROW_BYTES).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
