@@ -160,10 +160,13 @@ class SparseResult:
                         the producer supplied ``live_ids``)
         first_event[k]  event index of the first accepting OPEN
 
-    Rows are sorted by (doc, column).  ``verdict_bytes`` is what delivery
-    actually moves: 12 bytes per match instead of the dense ``B × Q × 5``
-    — the whole point at 10⁵⁺ subscriptions, where selectivity is low
-    and the dense bitmap is almost entirely zeros.
+    Rows are sorted by (doc, column) in column space.  Ids renamed
+    through a non-monotone ``live_ids`` (``FilterResult.sparsify``) may
+    not ascend within a document; :meth:`rows_by_document` restores the
+    order.  ``verdict_bytes`` is what delivery actually moves: 12 bytes
+    per match instead of the dense ``B × Q × 5`` — the whole point at
+    10⁵⁺ subscriptions, where selectivity is low and the dense bitmap is
+    almost entirely zeros.
 
     ``overflowed=True`` records that the bounded device match buffer
     overflowed and the rows came from the dense fallback instead — the
@@ -218,6 +221,28 @@ class SparseResult:
     def matching_queries(self, doc: int) -> np.ndarray:
         """Matching column/global ids of one document, ascending."""
         return np.sort(self.query_ids[self.doc_ids == doc])
+
+    def rows_by_document(self, n_docs: int | None = None
+                         ) -> tuple[np.ndarray, np.ndarray, bool]:
+        """Every document's ids in one pass: ``(ids, bounds, resorted)``.
+
+        Document ``i``'s ids, ascending, are ``ids[bounds[i]:bounds[i +
+        1]]``, as :meth:`matching_queries` gives them; ``ids`` holds the
+        rows of the first ``n_docs`` batch rows (default all) and no
+        others.  Producers emit rows in (doc, id) order, which one O(M)
+        check confirms; ``resorted`` says it failed and the rows took one
+        ``lexsort``.
+        """
+        docs, ids = self.doc_ids, self.query_ids
+        ordered = (docs[1:] > docs[:-1]) | ((docs[1:] == docs[:-1])
+                                            & (ids[1:] >= ids[:-1]))
+        resorted = not ordered.all()
+        if resorted:
+            order = np.lexsort((ids, docs))
+            docs, ids = docs[order], ids[order]
+        n = self.batch_size if n_docs is None else n_docs
+        bounds = np.searchsorted(docs, np.arange(n + 1))
+        return ids[:bounds[-1]], bounds, resorted
 
     def densify(self) -> FilterResult:
         """Exact dense reconstruction (round-trip of ``sparsify``)."""

@@ -212,6 +212,8 @@ class FilterStage:
                       # tag starts the bytes kernel walked and the
                       # chunks it fetched from HBM (``meta``)
                       "tag_starts": 0, "stream_chunks": 0,
+                      # fan-outs whose rows were not in (doc, id) order
+                      "fan_out_resorted": 0,
                       **dict.fromkeys(SPAN_KEYS, 0.0),
                       # sparse batches per engine route (``meta["path"]``)
                       "verdict_paths": {}}
@@ -635,28 +637,39 @@ class FilterStage:
         possibly non-contiguous document indices (the serve loop's
         quarantine retries filter recovered subsets whose seqs are not
         ``base + i``)."""
-        sparse = isinstance(results, SparseResult)
+        if not isinstance(results, SparseResult):
+            results = results.sparsify()  # (doc, column) rows of the bitmap
+        rows, bounds, resorted = results.rows_by_document(len(nbytes))
+        if resorted:
+            self.stats["fan_out_resorted"] += 1
+        # result columns are live-query columns; route by global id
+        # through the partition index so churn/sharding never change
+        # which data shard a profile delivers to.  Sparse producers
+        # with live_ids already speak global ids.
         live = self._gids if gids is None else gids
+        ids = rows if results.live_ids is not None else live[rows]
+        shards = self.shard_of_profile[ids]
+        one_shard = not shards.size or bool((shards == shards[0]).all())
+        if not one_shard:
+            # within each document, group its ids by shard, ascending;
+            # the sort is stable, so ids stay ascending within a group
+            doc_of = np.repeat(np.arange(len(nbytes)), np.diff(bounds))
+            order = np.lexsort((shards, doc_of))
+            ids, shards = ids[order], shards[order]
+        bounds = bounds.tolist()
         out: list[RoutedDocument] = []
         for i, nb in enumerate(nbytes):
             doc = base + i if seqs is None else int(seqs[i])
-            # result columns are live-query columns; route by global id
-            # through the partition index so churn/sharding never change
-            # which data shard a profile delivers to.  Sparse producers
-            # with live_ids already speak global ids.
-            if sparse:
-                qids = results.matching_queries(i)
-                if results.live_ids is None:
-                    qids = live[qids]
-            else:
-                qids = live[results[i].matching_queries()]
-            if len(qids) == 0:
+            lo, hi = bounds[i], bounds[i + 1]
+            if lo == hi:
                 if self.keep_unmatched:
-                    out.append(RoutedDocument(doc, qids, 0, nb))
+                    out.append(RoutedDocument(doc, ids[lo:hi], 0, nb))
                 continue
-            for shard in np.unique(self.shard_of_profile[qids]):
-                mine = qids[self.shard_of_profile[qids] == shard]
-                out.append(RoutedDocument(doc, mine, int(shard), nb))
+            cuts = ([] if one_shard else
+                    (lo + 1 + np.flatnonzero(shards[lo + 1:hi]
+                                             != shards[lo:hi - 1])).tolist())
+            for a, b in zip([lo, *cuts], [*cuts, hi]):
+                out.append(RoutedDocument(doc, ids[a:b], int(shards[a]), nb))
         return out
 
     # ------------------------------------------------------------- metrics
